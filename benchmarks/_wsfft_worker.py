@@ -16,12 +16,13 @@ import repro.fft as fft  # noqa: E402
 from repro.core import twiddle as tw  # noqa: E402
 from repro.core import wse_model as wm  # noqa: E402
 from benchmarks.common import emit, time_jax  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
     n = int(sys.argv[3])
     method = sys.argv[4] if len(sys.argv) > 4 else "auto"
-    mesh = jax.make_mesh((nx, ny), ("x", "y"))
+    mesh = make_mesh((nx, ny), ("x", "y"))
     # donate=False: the timing loop re-feeds the same planar buffers
     p = fft.plan((n, n, n), mesh, method=method, donate=False)
     rng = np.random.default_rng(0)

@@ -45,6 +45,7 @@ import numpy as np                            # noqa: E402
 import repro.fft as fft                       # noqa: E402
 from repro.launch import hlostats             # noqa: E402
 from benchmarks.common import time_jax, emit  # noqa: E402
+from repro.launch.mesh import make_mesh       # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_fftconv.json")
 
@@ -119,7 +120,7 @@ def main(argv=None):
     n = 2 * S
     batch = (2, 4) if args.smoke else (4, 8)      # (B, d)
 
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     print(f"# bench_fftconv: causal conv len n={n}, batch {batch}, "
           f"4x4 mesh ({jax.default_backend()})")
     print("kind,strategy,us,dispatches,wire_bytes")

@@ -55,6 +55,7 @@ from repro.fft import methods               # noqa: E402
 from repro.fft import pencil as fpencil     # noqa: E402
 from repro.launch import hlostats           # noqa: E402
 from benchmarks.common import time_jax, emit  # noqa: E402
+from repro.launch.mesh import make_mesh     # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_kernels.json")
 
@@ -80,7 +81,7 @@ def bench_local(method, b, n, tier):
 
 
 def bench_superstep(tier, n):
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     plan = fft.plan((n, n, n), mesh, method="stockham", kernel=tier,
                     donate=False)
     rng = np.random.default_rng(2)

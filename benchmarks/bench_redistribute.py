@@ -53,8 +53,8 @@ import numpy as np                          # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro import comm                      # noqa: E402
-from repro.core.compat import shard_map     # noqa: E402
 from benchmarks.common import time_jax, emit  # noqa: E402
+from repro.launch.mesh import make_mesh     # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_redistribute.json")
 
@@ -86,8 +86,8 @@ def bench_swap(mesh, group, strategy, mem_dim, rows, jdtype,
         return comm.strategies.swap_axes_wire(
             st, a, group, shard_pos=0, mem_pos=1, wire_dtype=wire)
 
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P(group, None),
-                           out_specs=P(None, group)))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(group, None),
+                               out_specs=P(None, group), check_vma=False))
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (rows * comm.strategies.static_group_size(group, dict(mesh.shape)),
          mem_dim)), jdtype)
@@ -118,7 +118,7 @@ def main(argv=None) -> None:
     print("mesh,group,strategy,p,local_elems,dtype,us,model_cycles")
     results = []
     for mesh_dims, names in meshes:
-        mesh = jax.make_mesh(mesh_dims, names)
+        mesh = make_mesh(mesh_dims, names)
         mesh_shape = dict(mesh.shape)
         trees = TREES.get(mesh_dims, ())
         strategies = comm.names() + (trees[:1] if args.smoke else trees)

@@ -38,6 +38,7 @@ import repro.fft as fft                      # noqa: E402
 from repro import comm                       # noqa: E402
 from repro.launch import hlostats            # noqa: E402
 from benchmarks.common import time_jax, emit  # noqa: E402
+from repro.launch.mesh import make_mesh      # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_rfft.json")
 
@@ -84,7 +85,7 @@ def main(argv=None):
     iters = 3 if args.smoke else args.iters
     strategies = ('all_to_all',) if args.smoke else comm.names()
 
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     shape = (n, n, n)
     print(f"# bench_rfft: fwd+inv round trip, {n}^3 on 4x4 "
           f"({jax.default_backend()})")

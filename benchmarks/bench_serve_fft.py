@@ -52,6 +52,7 @@ import repro.fft as fft                      # noqa: E402
 from repro import comm                       # noqa: E402
 from repro.serve import FFTEngine            # noqa: E402
 from benchmarks.common import emit           # noqa: E402
+from repro.launch.mesh import make_mesh      # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "BENCH_serve_fft.json")
 
@@ -264,7 +265,7 @@ def main(argv=None):
     if args.smoke and shapes_spec is None:
         shapes_spec = '8,16x16'                # exercise the drainer in CI
 
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     shape = (n, n, n)
     print(f"# bench_serve_fft: {n_requests} requests of {n}^3 on 4x4 "
           f"({jax.default_backend()})")

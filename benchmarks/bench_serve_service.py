@@ -52,6 +52,7 @@ from repro.comm import cost as ccost         # noqa: E402
 from repro.serve import (AdaptivePolicy, FaultPlan, FaultPoint,  # noqa: E402
                          FFTEngine, FFTService, SLOClass, TenantConfig)
 from benchmarks.common import emit           # noqa: E402
+from repro.launch.mesh import make_mesh      # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..",
                    "BENCH_serve_service.json")
@@ -231,7 +232,7 @@ def main(argv=None):
     repeats = 1 if args.smoke else args.repeats
     n_overhead = 12 if args.smoke else args.requests
 
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     sock = os.path.join(tempfile.mkdtemp(prefix="bench_serve_service_"),
                         "s.sock")
     shape_s = 'x'.join(map(str, SHAPE))

@@ -76,8 +76,7 @@ def run(n: int, *, pods: int = 1, method: str = 'auto',
     stats['collective_bytes_raw_total'] = stats['collective_bytes_total']
     stats['collective_bytes'] = wire['collective_bytes']
     stats['collective_bytes_total'] = wire['collective_bytes_total']
-    from repro.core.compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     roof = roofline_terms(stats, n_chips,
                           cost_flops=float(cost.get('flops', 0.0)),
                           cost_bytes=float(cost.get('bytes accessed', 0.0)))

@@ -36,6 +36,7 @@ import numpy as np              # noqa: E402
 import repro.fft as fft         # noqa: E402
 from repro.serve import (FFTClient, FFTService, RetryAfter,  # noqa: E402
                          TenantConfig)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
@@ -44,7 +45,7 @@ def main():
     ap.add_argument('--requests', type=int, default=10)
     args = ap.parse_args()
     n = args.n
-    mesh = jax.make_mesh((4, 4), ('x', 'y'))
+    mesh = make_mesh((4, 4), ('x', 'y'))
     shapes = [(n, n, n), (n, n)]
     rng = np.random.default_rng(7)
 

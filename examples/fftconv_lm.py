@@ -27,6 +27,7 @@ from repro.data import SyntheticLM
 from repro.models import model as M
 from repro.train.optim import adamw_init
 from repro.train.trainstep import make_train_step
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -41,7 +42,7 @@ def main():
         smoke_config(get_config('mamba2-1.3b')),
         block_pattern=('fftconv',), num_layers=4, d_model=64,
         vocab_size=256, fftconv_len=args.seq)
-    mesh = jax.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
 
     step = jax.jit(make_train_step(cfg, mesh, peak_lr=3e-3,
                                    warmup_steps=10, total_steps=args.steps,

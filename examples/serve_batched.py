@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.models import model as M
 from repro.serve import ServeEngine
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -30,7 +31,7 @@ def main():
     cfg = smoke_config(get_config(args.arch))
     if not cfg.causal:
         raise SystemExit(f'{cfg.name} is encoder-only — no decode step')
-    mesh = jax.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
     with mesh:
         params = M.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
         eng = ServeEngine(cfg, mesh, params, batch=args.batch,

@@ -29,6 +29,7 @@ import numpy as np              # noqa: E402
 
 import repro.fft as fft         # noqa: E402
 from repro.serve import FFTEngine  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
@@ -41,7 +42,7 @@ def main():
                          'persist them to BENCH_serve_schedule.json')
     args = ap.parse_args()
     n = args.n
-    mesh = jax.make_mesh((4, 4), ('x', 'y'))
+    mesh = make_mesh((4, 4), ('x', 'y'))
     shapes = [(n, n, n), (n // 2, n // 2, n // 2), (n, n)]
     rng = np.random.default_rng(0)
 

@@ -23,6 +23,7 @@ from repro.models import model as M
 from repro.runtime import StragglerMonitor, TrainDriver
 from repro.train.optim import adamw_init
 from repro.train.trainstep import make_train_step
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -46,7 +47,7 @@ def main():
         # untied LM head: at tiny scale a tied head couples input/output
         # embedding gradients and stalls early learning (measured)
         tie_embeddings=False)
-    mesh = jax.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
     ckpt = args.ckpt_dir or tempfile.mkdtemp(prefix='train_lm_')
 
     step = jax.jit(make_train_step(

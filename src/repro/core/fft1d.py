@@ -28,7 +28,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.comm.strategies import dbarrier
 from repro.core import twiddle as tw
 from repro.core.twiddle import Planar
 
@@ -64,9 +66,16 @@ def fft_stockham(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
         xi = im.reshape(batch + (2, c // 2, L))
         ar, ai = xr[..., 0, :, :], xi[..., 0, :, :]
         br, bi = xr[..., 1, :, :], xi[..., 1, :, :]
-        # t = w * b   (4 mul + 2 add, FMAC-fusable — paper Listing 1 l.36-42)
-        tr = br * wr - bi * wi
-        ti = br * wi + bi * wr
+        # t = w * b   (4 mul + 2 add — paper Listing 1 l.36-42). Each
+        # product is multiplied by a data-derived exact one, so it rounds
+        # before the add: XLA:CPU contracts a*b - c*d into an FMA, and
+        # which product it fuses depends on the fusion around the stage
+        # (a complex packing, an operator plan's pointwise stage), so
+        # unpinned butterflies give different bits in different programs
+        # (the trick of ``fft.spectral_mul``)
+        one = (ar - ar) + jnp.asarray(1.0, acc_dtype)
+        tr = (br * wr) * one - (bi * wi) * one
+        ti = (br * wi) * one + (bi * wr) * one
         re = jnp.concatenate([ar + tr, ar - tr], axis=-1).reshape(batch + (n,))
         im = jnp.concatenate([ai + ti, ai - ti], axis=-1).reshape(batch + (n,))
     if inverse:
@@ -201,7 +210,6 @@ def _block_consts_np(n1: int, n2: int, inverse: bool):
     so steps 3+4 are ONE batched matmul and no elementwise twiddle pass
     ever touches HBM. G is (2, n2, n1, 2, n2) ~ tiny constant.
     """
-    import numpy as np
     f1r, f1i = tw.dft_matrix_np(n1, inverse=inverse)
     f2r, f2i = tw.dft_matrix_np(n2, inverse=inverse)
     wr, wi = tw.four_step_twiddle_np(n1, n2, inverse=inverse)
@@ -316,9 +324,13 @@ def rfft_pencil(x: jnp.ndarray, *, cfft, dtype=None) -> Planar:
     if dtype is not None:
         x = x.astype(dtype)
     cr, ci = cfft(x[..., 0::2], x[..., 1::2])
-    # Cm[k] = C[(h - k) mod h] — a local index flip, no data movement
-    cmr = jnp.roll(jnp.flip(cr, -1), 1, -1)
-    cmi = jnp.roll(jnp.flip(ci, -1), 1, -1)
+    # Cm[k] = C[(h - k) mod h], read as a gather with constant indices:
+    # XLA:TPU computed a reverse of the pencil axis fused into the
+    # inverse's combine wrongly (see irfft_pencil), and of the forms
+    # measured on TPU v5e the gather is exact and the fastest here
+    mirror = (h - np.arange(h)) % h
+    cmr = jnp.take(cr, mirror, axis=-1)
+    cmi = jnp.take(ci, mirror, axis=-1)
     er, ei = (cr + cmr) * 0.5, (ci - cmi) * 0.5
     our, oui = (ci + cmi) * 0.5, (cmr - cr) * 0.5
     wr, wi = (jnp.asarray(a, cr.dtype) for a in tw.rfft_split_twiddle_np(n))
@@ -341,9 +353,15 @@ def irfft_pencil(re: jnp.ndarray, im: jnp.ndarray, *, cifft) -> jnp.ndarray:
     if h < 1:
         raise ValueError(f"irfft pencil needs >= 2 spectrum bins, got {nh}")
     ar, ai = re[..., :h], im[..., :h]
-    # Am[k] = A[h - k], k in [0, h)
-    amr = jnp.flip(re[..., 1:], -1)
-    ami = jnp.flip(im[..., 1:], -1)
+    # Am[k] = A[h - k], k in [0, h). The barrier keeps the reverse out of
+    # the combine's fusion: fused into it at large batch (>= 2^16
+    # pencils of 257 or 513 bins, as in the per-device (256, 256, 258)
+    # of the sharded 512^3 irfft), XLA:TPU computed it wrongly. (A
+    # gather, as in rfft_pencil, is exact there too, but on XLA:CPU it
+    # fuses differently per batch shape and batched executions stop
+    # being bit-identical to per-request ones.)
+    amr, ami = dbarrier((jnp.flip(re[..., 1:], -1),
+                         jnp.flip(im[..., 1:], -1)))
     er, ei = (ar + amr) * 0.5, (ai - ami) * 0.5
     # w^k O[k] = (A[k] - conj(Am[k])) / 2, then rotate by w^{-k}
     tr, ti = (ar - amr) * 0.5, (ai + ami) * 0.5

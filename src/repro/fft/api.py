@@ -364,10 +364,14 @@ class FFT:
         """The kernel tier this plan's supersteps run on the CURRENT
         backend ('pallas' | 'reference') — the 'auto' option resolved
         at query time against :data:`methods.PALLAS_LOWERING` and the
-        method's per-backend kernel table."""
-        n = self._factors[1] if self.rank == 1 else self.shape[-1]
-        return methods.resolve_kernel(self.kernel,
-                                      methods.resolve(self.method, n))
+        method's per-backend kernel table. Each pencil length resolves
+        on its own, as :func:`methods.apply` does per axis; the plan is
+        'pallas' only when every one of them is."""
+        lengths = set(self._factors if self.rank == 1 else self.shape)
+        tiers = {methods.resolve_kernel(self.kernel,
+                                        methods.resolve(self.method, n), n=n)
+                 for n in lengths}
+        return 'pallas' if tiers == {'pallas'} else 'reference'
 
     @property
     def donates_input(self) -> bool:
@@ -1014,7 +1018,7 @@ class SpectralOp(FFT):
         # ensure_compile_time_eval cannot be used here — its eval
         # trace unbinds the shard_map axis names the distributed
         # forward needs.
-        if jax.core.trace_state_clean():
+        if jax.core.trace_ctx.is_top_level():
             self._bake_now()
         else:
             box = []

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import comm as commlib
 from repro.comm import overlap as ov
-from repro.core.compat import shard_map
 from repro.fft import methods
 
 
@@ -162,8 +161,8 @@ def make_fft1d_large(n1: int, n2: int, plan_mesh, mesh_axes=('x', 'y'), *,
         return body(ar, ai)
 
     spec = P(*(((batch_spec,) if off else ()) + (mesh_axis, None)))
-    return shard_map(local, mesh=plan_mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec))
+    return jax.shard_map(local, mesh=plan_mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False)
 
 
 def _real_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
@@ -428,8 +427,8 @@ def make_fourstep_op(n1: int, n2: int, plan_mesh, mesh_axes, pointwise, *,
         in_specs = (tuple(bspec(nb) for nb in batch_ndims)
                     + tuple(s for nb in baked_batch_ndims
                             for s in (bspec(nb),) * 2))
-        return shard_map(local, mesh=plan_mesh, in_specs=in_specs,
-                         out_specs=bspec(batch_ndims[0]))
+        return jax.shard_map(local, mesh=plan_mesh, in_specs=in_specs,
+                             out_specs=bspec(batch_ndims[0]), check_vma=False)
 
     body_fwd, body_inv = _complex_fourstep(
         n1, n2, psize, mesh_axis, strategy, wire_dtype, method, kern,
@@ -457,8 +456,8 @@ def make_fourstep_op(n1: int, n2: int, plan_mesh, mesh_axes, pointwise, *,
                 + tuple(s for nb in baked_batch_ndims
                         for s in (bspec(nb),) * 2))
     out_spec = bspec(batch_ndims[0])
-    return shard_map(local_c, mesh=plan_mesh, in_specs=in_specs,
-                     out_specs=(out_spec, out_spec))
+    return jax.shard_map(local_c, mesh=plan_mesh, in_specs=in_specs,
+                         out_specs=(out_spec, out_spec), check_vma=False)
 
 
 def make_rfft1d_large(n1: int, n2: int, plan_mesh, mesh_axes=('x', 'y'), *,
@@ -524,7 +523,7 @@ def make_rfft1d_large(n1: int, n2: int, plan_mesh, mesh_axes=('x', 'y'), *,
 
     spec = P(*(((batch_spec,) if off else ()) + (mesh_axis, None)))
     if inverse:
-        return shard_map(local, mesh=plan_mesh, in_specs=(spec, spec),
-                         out_specs=spec)
-    return shard_map(local, mesh=plan_mesh, in_specs=(spec,),
-                     out_specs=(spec, spec))
+        return jax.shard_map(local, mesh=plan_mesh, in_specs=(spec, spec),
+                             out_specs=spec, check_vma=False)
+    return jax.shard_map(local, mesh=plan_mesh, in_specs=(spec,),
+                         out_specs=(spec, spec), check_vma=False)

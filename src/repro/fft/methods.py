@@ -32,7 +32,6 @@ previously it was reachable only through ``make_fft``.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
@@ -65,11 +64,6 @@ PALLAS_LOWERING: Dict[str, str] = {
     'tpu': 'mosaic',
 }
 
-#: env override for the interpret-mode default ('1'/'0'): CI forces
-#: interpret on, and a backend bringup can force native lowering.
-KERNEL_INTERPRET_ENV = 'REPRO_KERNEL_INTERPRET'
-
-
 def backend() -> str:
     """The active jax backend name ('cpu' | 'gpu' | 'tpu') — the key of
     every per-backend kernel/cost table (generalizes the old TPU-only
@@ -91,13 +85,8 @@ def pallas_lowering(bk: Optional[str] = None) -> str:
 
 def default_interpret(bk: Optional[str] = None) -> bool:
     """Interpret-mode default for Pallas calls: True exactly where the
-    backend has no native Pallas lowering. The old rule keyed off
-    ``on_tpu`` only, so a GPU backend silently ran its kernels op by op;
-    now GPU lowers via Triton. ``REPRO_KERNEL_INTERPRET=1/0`` overrides
-    (CI pins interpret on its fake-device host mesh)."""
-    env = os.environ.get(KERNEL_INTERPRET_ENV)
-    if env not in (None, ''):
-        return env.lower() not in ('0', 'false', 'no')
+    backend has no native Pallas lowering (the CPU). There is no
+    override: on the TPU the kernels always compile with Mosaic."""
     return pallas_lowering(bk) == 'interpret'
 
 
@@ -109,8 +98,14 @@ def validate_kernel(kernel: str) -> str:
     return kernel
 
 
+#: longest pencil the Mosaic kernels hold in VMEM (AOT-compiled for
+#: v5e); ``'auto'`` runs longer pencils on the reference tier
+PALLAS_MAX_N = 1024
+
+
 def resolve_kernel(kernel: str, method: Optional['Method'] = None,
-                   bk: Optional[str] = None) -> str:
+                   bk: Optional[str] = None,
+                   n: Optional[int] = None) -> str:
     """Resolve a kernel-tier option to the tier that will actually run:
     'pallas' or 'reference'.
 
@@ -120,7 +115,8 @@ def resolve_kernel(kernel: str, method: Optional['Method'] = None,
     plans are bit-identical to 'reference' plans by construction. An
     explicit 'pallas' runs everywhere (interpret mode where needed). A
     method with no kernel for this backend always falls back to
-    'reference', matching the old ``use_kernel`` behavior."""
+    'reference', matching the old ``use_kernel`` behavior, and so does
+    'auto' for a pencil of length ``n`` > :data:`PALLAS_MAX_N`."""
     validate_kernel(kernel)
     if kernel == 'reference':
         return 'reference'
@@ -129,6 +125,8 @@ def resolve_kernel(kernel: str, method: Optional['Method'] = None,
         return 'reference'
     if kernel == 'pallas':
         return 'pallas'
+    if n is not None and n > PALLAS_MAX_N:
+        return 'reference'
     return 'pallas' if pallas_lowering(bk) != 'interpret' else 'reference'
 
 
@@ -238,12 +236,12 @@ def apply(re: jnp.ndarray, im: jnp.ndarray, *, axis: int = -1,
             f"method {m.name!r} requires a power-of-two pencil length, "
             f"got {n} (use method='direct' or 'auto')")
     last = axis == re.ndim - 1
-    if resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m) == 'pallas':
+    if resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m,
+                      n=n) == 'pallas':
         kfn = m.kernel_for()
-        itp = default_interpret() if interpret is None else interpret
         if not last:
             re, im = jnp.moveaxis(re, axis, -1), jnp.moveaxis(im, axis, -1)
-        yr, yi = kfn(re, im, inverse=inverse, interpret=itp)
+        yr, yi = kfn(re, im, inverse=inverse, interpret=interpret)
         if not last:
             yr, yi = jnp.moveaxis(yr, -1, axis), jnp.moveaxis(yi, -1, axis)
         return yr, yi
@@ -317,14 +315,13 @@ def apply_block(x: jnp.ndarray, *, axis: int, inverse: bool = False,
         raise ValueError(
             f"method 'block' requires a power-of-two pencil length, got {n}")
     tier = resolve_kernel(_merge_kernel_arg(kernel, use_kernel),
-                          _REGISTRY.get('block'))
+                          _REGISTRY.get('block'), n=n)
     if tier == 'pallas':
         from repro.kernels import fft_block as _kb
-        itp = default_interpret() if interpret is None else interpret
         last = axis == x.ndim - 1
         if not last:
             x = jnp.moveaxis(x, axis, -1)
-        y = _kb.fft_block(x, inverse=inverse, interpret=itp)
+        y = _kb.fft_block(x, inverse=inverse, interpret=interpret)
         return y if last else jnp.moveaxis(y, -1, axis)
     return _f1.fft_four_step_block(x, axis, inverse=inverse,
                                    compute_dtype=compute_dtype)
@@ -357,14 +354,13 @@ def apply_fused(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
         raise ValueError(
             f"method {m.name!r} requires a power-of-two pencil length, "
             f"got {n} (use method='direct' or 'auto')")
-    tier = resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m)
+    tier = resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m, n=n)
     if tier == 'pallas':
-        itp = default_interpret() if interpret is None else interpret
         if m.name == 'stockham':
             from repro.kernels import fft_fused as _kf
             return _kf.fft_twiddle_transpose(
-                re, im, wr, wi, inverse=inverse, interpret=itp)
-        yr, yi = m.kernel_for()(re, im, inverse=inverse, interpret=itp)
+                re, im, wr, wi, inverse=inverse, interpret=interpret)
+        yr, yi = m.kernel_for()(re, im, inverse=inverse, interpret=interpret)
         if wr is not None:
             yr, yi = yr * wr - yi * wi, yr * wi + yi * wr
         return jnp.swapaxes(yr, -1, -2), jnp.swapaxes(yi, -1, -2)

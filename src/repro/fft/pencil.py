@@ -34,7 +34,6 @@ from jax.sharding import PartitionSpec as P
 from repro import comm
 from repro.comm import overlap as ov
 from repro.core import plan as planlib
-from repro.core.compat import shard_map
 from repro.core.plan import Layout, PencilPlan
 from repro.fft import methods
 
@@ -474,12 +473,13 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
             return c2r(re, im)
 
         if inverse:
-            fn = shard_map(local_real_inv, mesh=plan.mesh,
-                           in_specs=(in_spec, in_spec), out_specs=out_spec)
+            fn = jax.shard_map(local_real_inv, mesh=plan.mesh,
+                               in_specs=(in_spec, in_spec), out_specs=out_spec,
+                               check_vma=False)
         else:
-            fn = shard_map(local_real_fwd, mesh=plan.mesh,
-                           in_specs=(in_spec,),
-                           out_specs=(out_spec, out_spec))
+            fn = jax.shard_map(local_real_fwd, mesh=plan.mesh,
+                               in_specs=(in_spec,),
+                               out_specs=(out_spec, out_spec), check_vma=False)
         return fn, in_layout, out_layout
 
     def local(re, im):
@@ -521,9 +521,9 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
                         batch_ndim=batch_ndim, overlap_chunks=overlap_chunks,
                         fused=fused)
 
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(in_spec, in_spec),
-                   out_specs=(out_spec, out_spec))
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(in_spec, in_spec),
+                       out_specs=(out_spec, out_spec), check_vma=False)
     return fn, in_layout, out_layout
 
 
@@ -635,8 +635,9 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
         in_specs = (tuple(bspec(nb, in_layout) for nb in batch_ndims)
                     + tuple(s for nb in baked_batch_ndims
                             for s in (bspec(nb, spec_layout),) * 2))
-        fn = shard_map(local, mesh=plan.mesh, in_specs=in_specs,
-                       out_specs=bspec(batch_ndims[0], in_layout))
+        fn = jax.shard_map(local, mesh=plan.mesh, in_specs=in_specs,
+                           out_specs=bspec(batch_ndims[0], in_layout),
+                           check_vma=False)
         return fn, in_layout, spec_layout
 
     def local_c(*args):
@@ -669,8 +670,8 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
                 + tuple(s for nb in baked_batch_ndims
                         for s in (bspec(nb, spec_layout),) * 2))
     out_spec = bspec(batch_ndims[0], in_layout)
-    fn = shard_map(local_c, mesh=plan.mesh, in_specs=in_specs,
-                   out_specs=(out_spec, out_spec))
+    fn = jax.shard_map(local_c, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=(out_spec, out_spec), check_vma=False)
     return fn, in_layout, spec_layout
 
 

@@ -2,83 +2,38 @@
 
 The §Perf cell-A winner (EXPERIMENTS.md) as an MXU kernel: complex
 arithmetic via ONE real matmul per factor against the 2x2 block DFT
-matrix, and the inter-factor twiddle FOLDED into the second-factor
-matrices (G), so a superstep is exactly two dots with zero planar
-elementwise passes — the VMEM-resident form of core/fft1d.
-fft_four_step_block, which is its oracle.
-
-VMEM per grid step (fp32, n=4096, block_b=8): x+y tiles
-2*2*8*4096*4 = 1 MiB; F1b 2*64*2*64*4 = 128 KiB; G
-2*64*64*2*64*4 = 8 MiB -> fits with double buffering (G is the big
-constant; block sizes chosen so F1b/G stay resident across steps).
+matrix [[Fr, -Fi], [Fi, Fr]] — the VMEM-resident form of core/fft1d.
+fft_four_step_block, which is its oracle. It is the four-step kernel of
+:mod:`repro.kernels.fft_matmul` with step 3 as that single (2n1, 2n1)
+@ (2n1, BLOCK_B) dot; pencils of n <= 128 are one dense DFT matmul.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.core import fft1d as f1
 from repro.core import twiddle as tw
-
-DEFAULT_BLOCK_B = 8
-
-
-def _kernel(f1b_ref, g_ref, x_ref, y_ref, *, n1: int, n2: int, inverse: bool):
-    bb = x_ref.shape[1]
-    n = n1 * n2
-    f1b = f1b_ref[...]
-    g = g_ref[...]
-    a = x_ref[...].reshape(2, bb, n1, n2)
-    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    # step 2: one real dot computes both complex components
-    b = dot('cjdk,dakl->cajl', f1b, a)
-    # steps 3+4 fused: twiddle-folded second factor (+ output transpose)
-    d = dot('cmjdl,dajl->camj', g, b.astype(x_ref.dtype))
-    y = d.reshape(2, bb, n)
-    if inverse:
-        y = y * (1.0 / n)
-    y_ref[...] = y.astype(y_ref.dtype)
+from repro.kernels.fft_matmul import (DEFAULT_BLOCK_B, default_factors,
+                                      pallas_fft)
 
 
 @functools.partial(jax.jit, static_argnames=('inverse', 'block_b', 'interpret'))
 def fft_block(x: jnp.ndarray, *, inverse: bool = False,
               block_b: int = DEFAULT_BLOCK_B,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Batched block-complex pencil FFT. x: (2, ..., n) with the leading
     complex axis; transform along the last axis, natural order."""
     n = x.shape[-1]
-    n1, n2 = tw.four_step_factors(n)
+    if not tw.is_pow2(n):
+        raise ValueError(f"pencil length must be pow2, got {n}")
     batch_shape = x.shape[1:-1]
     b = int(np.prod(batch_shape)) if batch_shape else 1
     xr = x.reshape(2, b, n)
-    pad = (-b) % block_b
-    if pad:
-        xr = jnp.pad(xr, ((0, 0), (0, pad), (0, 0)))
-    bp = b + pad
-
-    dt = x.dtype
-    f1b_np, g_np = f1._block_consts_np(n1, n2, inverse)
-    f1b = jnp.asarray(f1b_np, dt)
-    g = jnp.asarray(g_np, dt)
-
-    grid = (bp // block_b,)
-    y = pl.pallas_call(
-        functools.partial(_kernel, n1=n1, n2=n2, inverse=inverse),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2, n1, 2, n1), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((2, n2, n1, 2, n2), lambda i: (0, 0, 0, 0, 0)),
-            pl.BlockSpec((2, block_b, n), lambda i: (0, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, block_b, n), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, bp, n), dt),
-        interpret=interpret,
-    )(f1b, g, xr)
-    if pad:
-        y = y[:, :b]
-    return y.reshape((2,) + batch_shape + (n,))
+    yr, yi = pallas_fft(xr[0], xr[1], inverse=inverse,
+                        factors=default_factors(n), block_b=block_b,
+                        interpret=interpret, block_complex=True)
+    return jnp.stack([yr, yi]).reshape((2,) + batch_shape + (n,))

@@ -7,16 +7,18 @@ just-transformed axis where the collective splits it. Each materialized
 an HBM-round-trip intermediate. This kernel is the whole superstep
 producer in one pass: a (BLOCK_B, n) tile of pencils is staged into
 VMEM, all log2(n) Stockham stages run in place (the same
-``_stockham_block`` the plain pencil kernel uses, so outputs stay
+``_stockham_rows`` the plain pencil kernel uses, so outputs stay
 bit-identical to the unfused tier), the twiddle tile is applied in
 registers, and the BlockSpec writes the tile *transposed* — the swap
 reads pre-rotated, pre-transposed data and XLA never emits the
-intermediate.
+intermediate. The stages already run with the pencil down the rows, so
+the transposed emit costs nothing extra: it is the stages' own layout.
 
 Grid: 2-D over (leading slices, batch tiles). The master twiddle table
 w_n^k, k in [0, n/2) is broadcast to every step exactly as in
 ``fft_pencil``; the optional inter-superstep twiddle (wr, wi) rides in
-with the same BlockSpec as the data.
+with the same BlockSpec as the data. The emitted (n, BLOCK_B) tile is
+lane-dense: BLOCK_B is 128, or the whole (padded) batch when smaller.
 """
 from __future__ import annotations
 
@@ -29,28 +31,27 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import twiddle as tw
-from repro.kernels.fft_pencil import DEFAULT_BLOCK_B, _stockham_block
+from repro.fft.methods import default_interpret
+from repro.kernels.fft_pencil import (DEFAULT_BLOCK_B, _stockham_rows,
+                                      block_rows, master_table)
 
 Planar = Tuple[jnp.ndarray, jnp.ndarray]
 
 
 def _kernel(mr_ref, mi_ref, xr_ref, xi_ref, *rest,
-            n: int, inverse: bool, has_w: bool):
+            n: int, inverse: bool, has_w: bool, pin: bool):
     if has_w:
         wr_ref, wi_ref, yr_ref, yi_ref = rest
     else:
         yr_ref, yi_ref = rest
-    b = xr_ref.shape[-2]
-    xr = xr_ref[...].reshape(b, n)
-    xi = xi_ref[...].reshape(b, n)
-    yr, yi = _stockham_block(xr, xi, mr_ref[...], mi_ref[...],
-                             n=n, inverse=inverse)
+    yr, yi = _stockham_rows(xr_ref[0].T, xi_ref[0].T, mr_ref, mi_ref,
+                            n=n, inverse=inverse, pin=pin)
     if has_w:
-        wr = wr_ref[...].reshape(b, n)
-        wi = wi_ref[...].reshape(b, n)
+        wr = wr_ref[0].T
+        wi = wi_ref[0].T
         yr, yi = yr * wr - yi * wi, yr * wi + yi * wr
-    yr_ref[...] = yr.T.reshape(yr_ref.shape)
-    yi_ref[...] = yi.T.reshape(yi_ref.shape)
+    yr_ref[0] = yr
+    yi_ref[0] = yi
 
 
 @functools.partial(jax.jit,
@@ -60,14 +61,14 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
                           wi: Optional[jnp.ndarray] = None, *,
                           inverse: bool = False,
                           block_b: int = DEFAULT_BLOCK_B,
-                          interpret: bool = True) -> Planar:
+                          interpret: Optional[bool] = None) -> Planar:
     """Fused superstep via pl.pallas_call. Input (..., b, n) planar;
     output (..., n, b): ``out[..., k, j] = (W * FFT(x))[..., j, k]``
     with the FFT along the last axis and W = (wr, wi) an optional planar
     twiddle broadcastable against the pre-transpose output (..., b, n).
 
     VMEM working set per grid step: 4-6 arrays * block_b * n * 4 B plus
-    the (n/2,) master table — same envelope as ``fft_pencil`` with one
+    the (n/2, 1) master table — same envelope as ``fft_pencil`` with one
     extra tile pair when the twiddle is present.
     """
     if re.ndim < 2:
@@ -88,8 +89,8 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
         twi = jnp.broadcast_to(jnp.asarray(wi, re.dtype),
                                re.shape).reshape(nl, b, n)
 
-    # pad batch to a multiple of block_b
-    pad = (-b) % block_b
+    bb = block_rows(b, block_b)
+    pad = (-b) % bb
     if pad:
         xr = jnp.pad(xr, ((0, 0), (0, pad), (0, 0)))
         xi = jnp.pad(xi, ((0, 0), (0, pad), (0, 0)))
@@ -98,28 +99,21 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
             twi = jnp.pad(twi, ((0, 0), (0, pad), (0, 0)))
     bp = b + pad
 
-    mr_np, mi_np = tw.roots_of_unity_np(n, inverse=inverse)
-    mr = jnp.asarray(mr_np[: n // 2], dtype=re.dtype)
-    mi = jnp.asarray(mi_np[: n // 2], dtype=re.dtype)
-
-    grid = (nl, bp // block_b)
-    tile_in = pl.BlockSpec((1, block_b, n), lambda l, i: (l, i, 0))
-    in_specs = [
-        pl.BlockSpec((n // 2,), lambda l, i: (0,)),     # master twiddle re
-        pl.BlockSpec((n // 2,), lambda l, i: (0,)),     # master twiddle im
-        tile_in,                                        # x re
-        tile_in,                                        # x im
-    ]
+    interpret = default_interpret() if interpret is None else interpret
+    mr, mi, table = master_table(n, inverse, re.dtype)
+    tile_in = pl.BlockSpec((1, bb, n), lambda l, i: (l, i, 0))
+    in_specs = [table, table, tile_in, tile_in]
     ops = [mr, mi, xr, xi]
     if has_w:
         in_specs += [tile_in, tile_in]                  # superstep twiddle
         ops += [twr, twi]
-    tile_out = pl.BlockSpec((1, n, block_b), lambda l, i: (l, 0, i))
+    tile_out = pl.BlockSpec((1, n, bb), lambda l, i: (l, 0, i))
     out_shape = [jax.ShapeDtypeStruct((nl, n, bp), re.dtype),
                  jax.ShapeDtypeStruct((nl, n, bp), im.dtype)]
     yr, yi = pl.pallas_call(
-        functools.partial(_kernel, n=n, inverse=inverse, has_w=has_w),
-        grid=grid,
+        functools.partial(_kernel, n=n, inverse=inverse, has_w=has_w,
+                          pin=interpret),
+        grid=(nl, bp // bb),
         in_specs=in_specs,
         out_specs=[tile_out, tile_out],
         out_shape=out_shape,

@@ -125,9 +125,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         t1 = time.time()
         compiled, spmd_txt = hlostats.compile_with_spmd_dump(lowered)
         t2 = time.time()
-        from repro.core.compat import cost_analysis_dict
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         txt = compiled.as_text()
         stats = hlostats.analyze(txt)
         # true-wire dtypes: CPU float-normalization widens bf16/f8

@@ -38,9 +38,9 @@ import time
 
 
 def _mesh(spec: str):
-    import jax
+    from repro.launch.mesh import make_mesh
     rows, cols = (int(t) for t in spec.split('x'))
-    return jax.make_mesh((rows, cols), ('x', 'y'))
+    return make_mesh((rows, cols), ('x', 'y'))
 
 
 def _address(spec: str):
@@ -300,6 +300,8 @@ def main(argv=None) -> None:
         os.environ['XLA_FLAGS'] = (
             f'--xla_force_host_platform_device_count={args.devices} '
             + os.environ.get('XLA_FLAGS', ''))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     args.fn(args)
 
 

@@ -32,6 +32,10 @@ def main() -> None:
     from repro.configs import get_config, smoke_config, make_batch
     from repro.models import model as M
     from repro.serve import ServeEngine
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -39,7 +43,7 @@ def main() -> None:
     if not cfg.causal:
         raise SystemExit(f'{cfg.name} is encoder-only: no decode step')
     rows, cols = (int(t) for t in args.mesh.split('x'))
-    mesh = jax.make_mesh((rows, cols), ('data', 'model'))
+    mesh = make_mesh((rows, cols), ('data', 'model'))
 
     with mesh:
         params = M.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)

@@ -47,12 +47,16 @@ def main() -> None:
     from repro.runtime import TrainDriver, FailureInjector, StragglerMonitor
     from repro.train.optim import adamw_init
     from repro.train.trainstep import jit_train_step
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
     rows, cols = (int(t) for t in args.mesh.split('x'))
-    mesh = jax.make_mesh((rows, cols), ('data', 'model'))
+    mesh = make_mesh((rows, cols), ('data', 'model'))
 
     sds = jax.ShapeDtypeStruct
     B, S = args.batch, args.seq

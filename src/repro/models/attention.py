@@ -196,9 +196,8 @@ def ulysses_attention(q, k, v, mesh, *, seq_axis: str = 'model',
         o = flash_attention(ql, kl, vl, causal=causal, window=window, chunk=chunk)
         return swap_out(o)
 
-    from repro.core.compat import shard_map
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

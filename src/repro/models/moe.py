@@ -229,11 +229,10 @@ def moe_ep_explicit(p: Dict, cfg, x, mesh, *, ep_axis: str = 'model',
     xspec = P(*batch_spec, seq_shard, None)
     wspec_i = P(ep_axis, fsdp_axes, None)
     wspec_o = P(ep_axis, None, fsdp_axes)
-    from repro.core.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(xspec, xspec, xspec, wspec_i, wspec_o),
-        out_specs=xspec)
+        out_specs=xspec, check_vma=False)
     y = fn(x, gates, idx, p['wi'], p['wo'])
     if 'shared' in p:
         y = y + L.apply_mlp(p['shared'], x)

@@ -84,6 +84,7 @@ import uuid
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.comm import cost as ccost
@@ -767,7 +768,7 @@ class FFTService:
                 self.engine._schedule_table, dict(self.engine.mesh.shape),
                 self.engine.shape, 'complex',
                 self.engine._plan_kwargs.get('comm', 'auto'),
-                backend=_jax_backend())
+                backend=jax.default_backend())
         self._apply_policy(force=True)
 
         self.address: Optional[Address] = address
@@ -1066,7 +1067,7 @@ class FFTService:
             rows.extend(self.policy.rows(
                 dict(self.engine.mesh.shape), shape,
                 'real' if real else 'complex', strategy,
-                backend=_jax_backend()))
+                backend=jax.default_backend()))
         if rows:
             try:
                 ccost.persist_schedule_rows(rows,
@@ -1532,14 +1533,6 @@ class FFTService:
                 f"tenants={sorted(self._tenants)}, "
                 f"inflight={self._inflight_total}/{self.max_inflight}, "
                 f"policy={'on' if self.policy else 'off'})")
-
-
-def _jax_backend() -> Optional[str]:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return None
 
 
 # ---------------------------------------------------------------------------

@@ -25,8 +25,8 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro import comm  # noqa: E402
-from repro.core.compat import shard_map  # noqa: E402
 import repro.fft as fft  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(11)
 
@@ -50,7 +50,8 @@ def run_swap(mesh, mesh_axis, strategy, x, shard_pos, mem_pos, ndim):
         return comm.swap_axes(a, mesh_axis, shard_pos=shard_pos,
                               mem_pos=mem_pos, strategy=strategy)
 
-    fn = shard_map(f, mesh=mesh, in_specs=P(*in_spec), out_specs=P(*out_spec))
+    fn = jax.shard_map(f, mesh=mesh, in_specs=P(*in_spec), out_specs=P(*out_spec),
+                       check_vma=False)
     return np.asarray(jax.jit(fn)(x))
 
 
@@ -94,7 +95,8 @@ def check_redistribute_roundtrip(mesh):
             def go(a, s=src, d=dst, n=name):
                 y = comm.redistribute(a, s, d, strategy=n)
                 return comm.redistribute(y, d, s, strategy=n)
-            fn = shard_map(go, mesh=mesh, in_specs=P(*src), out_specs=P(*src))
+            fn = jax.shard_map(go, mesh=mesh, in_specs=P(*src), out_specs=P(*src),
+                               check_vma=False)
             got = np.asarray(jax.jit(fn)(x))
             assert np.array_equal(got, np.asarray(x)), (src, dst, name)
         print(f"PASS redistribute round-trip {src} <-> {dst} (all strategies)")
@@ -251,11 +253,11 @@ def check_strategy_grads(mesh):
     for mesh_axis in ('x', 'y', ('x', 'y')):
         grads, cts = {}, {}
         for name in all_strategies():
-            f = shard_map(
+            f = jax.shard_map(
                 lambda a, n=name: comm.swap_axes(
                     a, mesh_axis, shard_pos=0, mem_pos=1, strategy=n),
                 mesh=mesh, in_specs=P(mesh_axis, None, None),
-                out_specs=P(None, mesh_axis, None))
+                out_specs=P(None, mesh_axis, None), check_vma=False)
             loss = jax.jit(lambda a, f=f: jnp.sum(jnp.sin(f(a)) * w))
             grads[name] = np.asarray(jax.grad(loss)(x))
             _, vjp = jax.vjp(jax.jit(f), x)
@@ -301,7 +303,7 @@ def check_moe_overlap(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     check_swaps(mesh)
     check_redistribute_roundtrip(mesh)
     check_facade_matrix(mesh)

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.core import distributed as dist  # noqa: E402
 from repro.core import plan as planlib  # noqa: E402
 from repro.core import twiddle as tw  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def check(name, got, want, tol):
@@ -25,7 +26,7 @@ def check(name, got, want, tol):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     rng = np.random.default_rng(42)
 
     # ---- 3D FFT, n^3 on 4x4 mesh (multi-pencil m = n/4) ----

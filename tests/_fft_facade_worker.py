@@ -19,6 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import repro.fft as fft  # noqa: E402
 from repro.core import twiddle as tw  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def check(name, got, want, tol):
@@ -33,7 +34,7 @@ def npfft(x, rank):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     rng = np.random.default_rng(7)
     shapes = {1: (1024,), 2: (32, 64), 3: (16, 16, 16)}
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import repro.fft as fft  # noqa: E402
 from repro.fft import pencil as fpencil  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 STRATEGIES = ("all_to_all", "ppermute", "hierarchical",
@@ -38,7 +39,7 @@ def check_bitwise(name, a, b):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     rng = np.random.default_rng(11)
     n = 16
     x = (rng.standard_normal((n, n, n))

@@ -20,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import repro.fft as fft  # noqa: E402
 from repro import comm  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(17)
 SHAPES = {1: (1024,), 2: (32, 64), 3: (16, 16, 16)}
@@ -214,7 +215,7 @@ def check_restore_layout(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     check_strategy_matrix(mesh)
     check_method_matrix(mesh)
     check_shardings(mesh)

@@ -31,6 +31,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import repro.fft as fft  # noqa: E402
 from repro.serve import FFTEngine  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(47)
 SHAPES = [(8, 8, 8), (4, 4, 4), (16, 16)]
@@ -212,7 +213,7 @@ def check_donated_inflight_snapshot(mesh, plans):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     plans = ref_plans(mesh)
     check_concurrent_producers(mesh, plans)
     check_deadline_only(mesh, plans)

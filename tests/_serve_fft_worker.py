@@ -22,6 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 import repro.fft as fft  # noqa: E402
 from repro import comm  # noqa: E402
 from repro.serve import FFTEngine  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(41)
 SHAPE = (16, 16, 16)
@@ -127,7 +128,7 @@ def check_engine_overlap_fallback(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     check_engine_bit_identity(mesh)
     check_engine_inverse_roundtrip(mesh)
     check_engine_donation(mesh)

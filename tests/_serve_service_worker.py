@@ -38,6 +38,7 @@ import jax.numpy as jnp  # noqa: E402
 import repro.fft as fft  # noqa: E402
 from repro.serve import (FFTClient, FFTEngine, FFTService,  # noqa: E402
                          RetryAfter, SLOClass, TenantConfig)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(53)
 SHAPES = [(8, 8, 8), (4, 4, 4), (16, 16)]
@@ -254,7 +255,7 @@ def case3_slo_ordering(eng, plans):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     plans = ref_plans(mesh)
     with FFTEngine(mesh=mesh, max_wait_ms=20.0,
                    schedule_table=None) as eng:

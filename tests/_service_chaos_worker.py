@@ -52,6 +52,7 @@ import repro.fft as fft  # noqa: E402
 from repro.serve import (BrownoutBreaker, FaultPlan, FaultPoint,  # noqa: E402
                          FFTClient, FFTEngine, FFTService, RetryAfter,
                          TenantConfig)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(101)
 SHAPES = [(8, 8, 8), (4, 4, 4)]
@@ -245,6 +246,10 @@ def case3_idempotent_resubmit(eng, plans):
     shape = SHAPES[0]
     xs = [creq(shape) for _ in range(4)]
     refs = [ref_forward(plans, shape, x) for x in xs]
+    # compile the single-request executable up front: a first request
+    # that spends longer than the 1 s heartbeat timeout compiling gets
+    # its connection reaped while inflight (re-attach, not re-delivery)
+    eng.transform([xs[3]])
 
     eng.set_drainer(watermark=1, max_wait_ms=5.0)
     # scripted: the FIRST result frame (writer hit 1, after HELLO_OK
@@ -447,7 +452,7 @@ def case5_hot_reload(eng, plans):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     plans = ref_plans(mesh)
     with FFTEngine(mesh=mesh, max_wait_ms=20.0,
                    schedule_table=None) as eng:

@@ -23,6 +23,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import repro.fft as fft  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(23)
 SHAPES = {1: (1024,), 2: (32, 64), 3: (16, 16, 16)}
@@ -252,7 +253,7 @@ def check_serving(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     check_strategy_matrix(mesh)
     check_complex(mesh)
     check_baked(mesh)

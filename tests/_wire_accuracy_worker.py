@@ -20,11 +20,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 
 import numpy as np  # noqa: E402
-import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro import comm  # noqa: E402
 import repro.fft as fft  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 RNG = np.random.default_rng(23)
 
@@ -97,7 +97,7 @@ def check_real(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("x", "y"))
+    mesh = make_mesh((4, 4), ("x", "y"))
     check_complex(mesh)
     check_real(mesh)
     print("WIRE_WORKER_OK")

@@ -14,6 +14,7 @@ import sys
 import pytest
 
 from repro import comm
+from repro.launch.mesh import make_abstract_mesh
 from repro.comm import cost as ccost
 from repro.core import plan as planlib
 from repro.core import wse_model as wm
@@ -109,12 +110,12 @@ def test_registry_contents():
 def test_make_fft_executes_with_auto_comm():
     """A PencilPlan carrying comm='auto' must execute, not just build
     (the executor resolves 'auto' to the default strategy)."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.core.plan import PencilPlan
     from repro.fft import pencil
-    mesh = jax.make_mesh((1, 1), ('x', 'y'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ('x', 'y'))
     plan = PencilPlan(shape=(8, 8, 8), mesh=mesh, layout=('x', 'y', None),
                       comm='auto')
     fn, _, _ = pencil.make_fft(plan)
@@ -208,7 +209,7 @@ def test_cost_report_via_abstract_mesh_facade():
     from jax import sharding
     if not hasattr(sharding, 'AbstractMesh'):
         pytest.skip("jax.sharding.AbstractMesh unavailable")
-    mesh = sharding.AbstractMesh((('x', 512), ('y', 512)))
+    mesh = make_abstract_mesh((512, 512), ('x', 'y'))
     import repro.fft as fft
     p = fft.plan((512,) * 3, mesh, method='stockham', comm='all_to_all')
     pc = p.plan_cost('fp32')

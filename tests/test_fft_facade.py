@@ -12,18 +12,18 @@ import sys
 
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
 import repro.fft as fft
 from repro.core import twiddle as tw
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("x", "y"))
+    return make_mesh((1, 1), ("x", "y"))
 
 
 RNG = np.random.default_rng(3)

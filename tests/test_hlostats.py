@@ -11,8 +11,9 @@ import sys; sys.path.insert(0, 'src')
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch import hlostats
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ('x', 'y'))
+mesh = make_mesh((2, 4), ('x', 'y'))
 def f(x, w):
     def body(c, _):
         c = jnp.tanh(c @ w)

@@ -57,8 +57,8 @@ def test_plan_swaps_roundtrip():
 
 
 def test_plan_local_shape_and_validate():
-    import jax
-    mesh = jax.make_mesh((1, 1), ('x', 'y'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ('x', 'y'))
     p = planlib.make_fft3d_plan(8, mesh)
     p.validate()
     assert p.local_shape() == (8, 8, 8)

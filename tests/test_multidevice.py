@@ -15,10 +15,11 @@ from repro.configs import get_config, smoke_config, make_batch
 from repro.models import model as M
 from repro.train.optim import adamw_init
 from repro.train.trainstep import jit_train_step
+from repro.launch.mesh import make_mesh
 
 for arch in ('internlm2-1.8b', 'dbrx-132b', 'mamba2-1.3b'):
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_mesh((2, 4), ('data', 'model'))
     sds = jax.ShapeDtypeStruct
     B, S = 4, 16
     b_sds = {'tokens': sds((B, S), jnp.int32), 'labels': sds((B, S), jnp.int32)}
@@ -45,8 +46,9 @@ import sys; sys.path.insert(0, 'src')
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.models import attention as A
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 B, S, H, KH, D = 2, 32, 8, 2, 16
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)

@@ -18,6 +18,7 @@ import repro.fft as fft
 from repro.comm import cost as ccost
 from repro.core import wse_model as wm
 from repro.fft import methods, pencil
+from repro.launch.mesh import make_abstract_mesh, make_mesh
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 RNG = np.random.default_rng(23)
@@ -25,7 +26,7 @@ RNG = np.random.default_rng(23)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("x", "y"))
+    return make_mesh((1, 1), ("x", "y"))
 
 
 # ---------------------------------------------------------------------------
@@ -45,6 +46,84 @@ def test_apply_real_matches_numpy(method):
     assert np.all(np.asarray(yi)[:, -1] == 0)
     back = methods.apply_real(yr, yi, inverse=True, method=method)
     np.testing.assert_allclose(np.asarray(back), x, atol=1e-4)
+
+
+def _reverse_readers(closed_jaxpr):
+    """Primitive names of the equations that read a reversed array: the
+    output of a ``rev``, or of a jitted call that is nothing but one
+    (``jnp.flip``), searched through nested jaxprs."""
+    from jax.core import jaxprs_in_params
+    from jax.extend.core import Literal
+
+    def only_rev(eqn):
+        subs = list(jaxprs_in_params(eqn.params))
+        return (eqn.primitive.name == "rev"
+                or (len(subs) == 1
+                    and [e.primitive.name for e in subs[0].eqns] == ["rev"]))
+
+    readers = []
+
+    def walk(jaxpr):
+        revs = set()
+        for eqn in jaxpr.eqns:
+            readers.extend(eqn.primitive.name for v in eqn.invars
+                           if not isinstance(v, Literal) and v in revs)
+            if only_rev(eqn):
+                revs.update(eqn.outvars)
+            else:
+                for sub in jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(closed_jaxpr.jaxpr)
+    return readers
+
+
+@pytest.mark.parametrize("n", [2, 4, 16, 64, 512])
+@pytest.mark.parametrize("method", ["stockham", "four_step"])
+def test_c2r_epilogue_has_no_reverse(n, method):
+    """The c2r epilogue of the sharded irfft: padded half spectrum ->
+    slice to n//2 + 1 bins -> inverse real transform. No reverse of the
+    pencil axis is fused into its Hermitian combine (XLA:TPU computed
+    that fused reverse wrongly at the 512^3 per-device shape): the
+    mirrored bins pass an optimization barrier before any arithmetic
+    reads them. It matches numpy.fft.irfft on a Hermitian half
+    spectrum. (XLA:CPU drops the barrier when it fuses, so the compiled
+    TPU program is checked in test_tpu_compile.py.)"""
+    nh = n // 2 + 1
+    spec = (RNG.standard_normal((3, 2, nh + 1))
+            + 1j * RNG.standard_normal((3, 2, nh + 1))).astype(np.complex64)
+    spec[..., [0, nh - 1]] = spec[..., [0, nh - 1]].real     # real DC, Nyquist
+
+    def c2r(re, im):
+        return methods.apply_real(re[..., :nh], im[..., :nh], axis=-1,
+                                  inverse=True, method=method)
+
+    re, im = jnp.asarray(spec.real), jnp.asarray(spec.imag)
+    readers = _reverse_readers(jax.make_jaxpr(c2r)(re, im))
+    assert readers and set(readers) == {"custom_vjp_call"}, readers
+    got = np.asarray(jax.jit(c2r)(re, im), np.float64)
+    want = np.fft.irfft(spec[..., :nh].astype(np.complex128), n=n, axis=-1)
+    np.testing.assert_allclose(got, want, atol=2e-6 * np.sqrt(n))
+
+
+@pytest.mark.parametrize("n", [2, 4, 16, 64, 512])
+@pytest.mark.parametrize("method", ["stockham", "four_step"])
+def test_r2c_combine_has_no_reverse(n, method):
+    """The forward's Hermitian combine reads its mirrored bins by a
+    gather, with no reverse of the pencil axis anywhere, and matches
+    numpy.fft.rfft."""
+    x = RNG.standard_normal((3, 2, n)).astype(np.float32)
+
+    def r2c(a):
+        return methods.apply_real(a, axis=-1, method=method)
+
+    xd = jnp.asarray(x)
+    assert "rev" not in str(jax.make_jaxpr(r2c)(xd))
+    assert "reverse(" not in jax.jit(r2c).lower(xd).compile().as_text()
+    yr, yi = jax.jit(r2c)(xd)
+    got = np.asarray(yr, np.float64) + 1j * np.asarray(yi, np.float64)
+    want = np.fft.rfft(x.astype(np.float64), axis=-1)
+    np.testing.assert_allclose(got, want, atol=2e-6 * np.sqrt(n))
 
 
 def test_apply_real_axis_general():
@@ -203,7 +282,7 @@ def test_rplan_facade_cost_on_abstract_mesh():
     from jax import sharding
     if not hasattr(sharding, 'AbstractMesh'):
         pytest.skip("jax.sharding.AbstractMesh unavailable")
-    amesh = sharding.AbstractMesh((('x', 16), ('y', 16)))
+    amesh = make_abstract_mesh((16, 16), ('x', 'y'))
     pr = fft.rplan((512,) * 3, amesh, comm='all_to_all',
                    padded_spectrum=True)
     pc = fft.plan((512,) * 3, amesh, comm='all_to_all')

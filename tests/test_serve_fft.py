@@ -12,13 +12,13 @@ import sys
 
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
 import repro.fft as fft
 from repro.comm import cost as ccost
 from repro.comm import overlap as ov
 from repro.serve import FFTEngine
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 RNG = np.random.default_rng(29)
@@ -26,7 +26,7 @@ RNG = np.random.default_rng(29)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("x", "y"))
+    return make_mesh((1, 1), ("x", "y"))
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.comm import cost as ccost  # noqa: E402
+from repro.launch.mesh import make_abstract_mesh  # noqa: E402
 from repro.serve import FFTEngine, LRUPlanCache  # noqa: E402
 
 # ---------------------------------------------------------------------------
@@ -79,7 +80,7 @@ def test_schedule_pick_respects_knobs(n, maxc, budget):
     sharding = pytest.importorskip("jax.sharding")
     if not hasattr(sharding, 'AbstractMesh'):
         pytest.skip("jax.sharding.AbstractMesh unavailable")
-    mesh = sharding.AbstractMesh((('x', 4), ('y', 4)))
+    mesh = make_abstract_mesh((4, 4), ('x', 'y'))
     eng = FFTEngine((n, n, n), mesh, max_coalesce=maxc,
                     latency_budget_us=budget, schedule_table=None)
     w, c = eng.schedule(False)

@@ -16,13 +16,13 @@ import time
 
 import numpy as np
 import pytest
-import jax
 
 from repro.comm import cost as ccost
 from repro.serve import (AdaptivePolicy, FFTClient, FFTEngine, FFTService,
                          LRUPlanCache, RateEstimator, ResultTimeout,
                          RetryAfter, SLOClass, TenantConfig)
 from repro.serve import protocol as proto
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 RNG = np.random.default_rng(29)
@@ -30,7 +30,7 @@ RNG = np.random.default_rng(29)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("x", "y"))
+    return make_mesh((1, 1), ("x", "y"))
 
 
 @pytest.fixture()

@@ -1,16 +1,16 @@
 """Sharding rules: divisibility guard and axis-collision guard.
 Hypothesis property tests over arbitrary shapes live in
 test_sharding_properties.py (skipped without hypothesis)."""
-import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel import make_rules, spec_for
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope='module')
 def mesh():
-    return jax.make_mesh((1, 1), ('data', 'model'))
+    return make_mesh((1, 1), ('data', 'model'))
 
 
 # rules bound to a *virtual* 16x16 mesh for pure spec logic (no devices)

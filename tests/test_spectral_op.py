@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import repro.fft as fft
 from repro.fft import methods as fftm
 from repro.models import ssd
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -28,7 +29,7 @@ RNG = np.random.default_rng(11)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("x", "y"))
+    return make_mesh((1, 1), ("x", "y"))
 
 
 def _pw_scale(re, im):
@@ -321,7 +322,7 @@ def test_fftconv_lm_loss_parity(monkeypatch):
         smoke_config(get_config('mamba2-1.3b')),
         block_pattern=('fftconv',), num_layers=2, d_model=16,
         vocab_size=64, fftconv_len=16)
-    lm_mesh = jax.make_mesh((1, 1), ('data', 'model'))
+    lm_mesh = make_mesh((1, 1), ('data', 'model'))
     new_mixer = ssd.fftconv_apply
 
     def batches():

@@ -14,11 +14,12 @@ from repro.models import model as M
 from repro.runtime import FailureInjector, StragglerMonitor, TrainDriver
 from repro.train.optim import adamw_init
 from repro.train.trainstep import make_train_step
+from repro.launch.mesh import make_mesh
 
 
 def _setup(arch='internlm2-1.8b', B=4, S=32, lr=3e-3):
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
     step = make_train_step(cfg, mesh, peak_lr=lr, warmup_steps=5,
                            total_steps=60, param_dtype=jnp.float32)
     step = jax.jit(step, donate_argnums=(0, 1))
@@ -84,12 +85,12 @@ def test_elastic_reshard_restore(tmp_path):
     (the elastic re-mesh path) with identical values."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import restore_checkpoint, save_checkpoint
-    mesh1 = jax.make_mesh((1, 1), ('data', 'model'))
+    mesh1 = make_mesh((1, 1), ('data', 'model'))
     t = {'w': jax.device_put(
         jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
         NamedSharding(mesh1, P('data', None)))}
     save_checkpoint(str(tmp_path), 1, t)
-    mesh2 = jax.make_mesh((1, 1), ('a', 'b'))      # a "different fleet"
+    mesh2 = make_mesh((1, 1), ('a', 'b'))      # a "different fleet"
     sh = {'w': NamedSharding(mesh2, P(None, 'b'))}
     r = restore_checkpoint(str(tmp_path), 1, t, sh)
     np.testing.assert_array_equal(np.asarray(r['w']), np.asarray(t['w']))

@@ -18,6 +18,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro import comm
+from repro.launch.mesh import make_abstract_mesh
 from repro.comm import cost as ccost
 from repro.comm import strategies as strat
 from repro.core import wse_model as wm
@@ -30,7 +31,7 @@ def _abstract_mesh(*sizes, names=('x', 'y')):
     sharding = pytest.importorskip("jax.sharding")
     if not hasattr(sharding, 'AbstractMesh'):
         pytest.skip("jax.sharding.AbstractMesh unavailable")
-    return sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return make_abstract_mesh(sizes, names)
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +368,21 @@ def test_with_options_roundtrips_kernel_tier():
     # 'auto' resolves to 'reference' on this CPU host
     pa = fft.plan((32, 32, 32), mesh)
     assert pa.kernel == 'auto' and pa.resolved_kernel == 'reference'
+
+
+def test_resolved_kernel_covers_every_axis(monkeypatch):
+    """On a backend that lowers Pallas natively, 'auto' resolves each
+    pencil length on its own: a plan reports the Pallas tier only when
+    every axis runs it, and one axis past ``PALLAS_MAX_N`` (reference
+    tier) makes the whole plan report 'reference'."""
+    import repro.fft as fft
+    from repro.fft import methods
+    monkeypatch.setattr(methods, 'backend', lambda: 'tpu')
+    mesh = _abstract_mesh(4, 4)
+    n = methods.PALLAS_MAX_N
+    assert fft.plan((n, 64, 64), mesh).resolved_kernel == 'pallas'
+    for shape in ((2 * n, 64, 64), (64, 2 * n, 64), (64, 64, 2 * n)):
+        assert fft.plan(shape, mesh).resolved_kernel == 'reference', shape
 
 
 def test_plan_rejects_unknown_kernel_tier():
