@@ -35,6 +35,8 @@ from typing import Callable, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 
 def pick_chunk_axis(local_shape: Sequence[int], exclude: Sequence[int],
                     n_chunks: int) -> Optional[int]:
@@ -65,12 +67,15 @@ def pipelined(n_chunks: int, axis: int, fn: Callable, *arrays: jnp.ndarray):
     """
     if n_chunks <= 1:
         return fn(*arrays)
-    parts = zip(*(jnp.split(a, n_chunks, axis=axis) for a in arrays))
+    with scopes.scope(scopes.RELAYOUT):
+        parts = list(zip(*(jnp.split(a, n_chunks, axis=axis)
+                           for a in arrays)))
     outs = [fn(*chunk) for chunk in parts]
-    if isinstance(outs[0], tuple):
-        return tuple(jnp.concatenate([o[k] for o in outs], axis=axis)
-                     for k in range(len(outs[0])))
-    return jnp.concatenate(outs, axis=axis)
+    with scopes.scope(scopes.RELAYOUT):
+        if isinstance(outs[0], tuple):
+            return tuple(jnp.concatenate([o[k] for o in outs], axis=axis)
+                         for k in range(len(outs[0])))
+        return jnp.concatenate(outs, axis=axis)
 
 
 class StreamPipeline:
@@ -195,7 +200,8 @@ def overlapped_fft_swap(re: jnp.ndarray, im: jnp.ndarray, *,
         cr, ci = fft_fn(cr, ci)
         out = []
         for c in (cr, ci):
-            w, restore = _strat.wire_cast(c, wire_dtype)
-            out.append(_strat.wire_restore(swap_fn(w), restore))
+            with scopes.scope(scopes.SWAP):
+                w, restore = _strat.wire_cast(c, wire_dtype)
+                out.append(_strat.wire_restore(swap_fn(w), restore))
         return tuple(out)
     return pipelined(n_chunks, chunk_axis, stage, re, im)

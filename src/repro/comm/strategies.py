@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import plan as planlib
+from repro.core import scopes
 from repro.core import wse_model as wm
 from repro.core.plan import Layout, MeshAxis
 
@@ -153,7 +154,8 @@ class Strategy:
              mem_pos: int) -> Tuple[jax.Array, Layout]:
         """swap + layout bookkeeping."""
         sp = planlib.owner_pos(layout, mesh_axis)
-        y = self.swap_axes(x, mesh_axis, shard_pos=sp, mem_pos=mem_pos)
+        with scopes.scope(scopes.SWAP):
+            y = self.swap_axes(x, mesh_axis, shard_pos=sp, mem_pos=mem_pos)
         return y, planlib.swap(layout, mesh_axis, mem_pos)
 
     def cost(self, mesh_axis: MeshAxis, mesh_shape, elems: float,
@@ -296,10 +298,11 @@ def swap_axes_wire(strategy: 'Strategy', x: jax.Array, mesh_axis: MeshAxis,
     """One ownership swap with the operand cast to the compact wire
     format around the collective only — all upstream/downstream compute
     sees the original dtype."""
-    w, restore = wire_cast(x, wire_dtype)
-    y = strategy.swap_axes(w, mesh_axis, shard_pos=shard_pos,
-                           mem_pos=mem_pos)
-    return wire_restore(y, restore)
+    with scopes.scope(scopes.SWAP):
+        w, restore = wire_cast(x, wire_dtype)
+        y = strategy.swap_axes(w, mesh_axis, shard_pos=shard_pos,
+                               mem_pos=mem_pos)
+        return wire_restore(y, restore)
 
 
 # ---------------------------------------------------------------------------

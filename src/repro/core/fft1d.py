@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.comm.strategies import dbarrier
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.core.twiddle import Planar
 
@@ -295,7 +296,8 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
     yr, yi = fft_fn(re, im, inverse=inverse, compute_dtype=compute_dtype)
     if wr is not None:
         yr, yi = yr * wr - yi * wi, yr * wi + yi * wr
-    return jnp.swapaxes(yr, -1, -2), jnp.swapaxes(yi, -1, -2)
+    with scopes.scope(scopes.RELAYOUT):
+        return jnp.swapaxes(yr, -1, -2), jnp.swapaxes(yi, -1, -2)
 
 
 # ---------------------------------------------------------------------------
@@ -321,25 +323,30 @@ def rfft_pencil(x: jnp.ndarray, *, cfft, dtype=None) -> Planar:
     if n % 2:
         raise ValueError(f"rfft pencil needs an even length, got {n}")
     h = n // 2
-    if dtype is not None:
-        x = x.astype(dtype)
-    cr, ci = cfft(x[..., 0::2], x[..., 1::2])
-    # Cm[k] = C[(h - k) mod h], read as a gather with constant indices:
-    # XLA:TPU computed a reverse of the pencil axis fused into the
-    # inverse's combine wrongly (see irfft_pencil), and of the forms
-    # measured on TPU v5e the gather is exact and the fastest here
-    mirror = (h - np.arange(h)) % h
-    cmr = jnp.take(cr, mirror, axis=-1)
-    cmi = jnp.take(ci, mirror, axis=-1)
-    er, ei = (cr + cmr) * 0.5, (ci - cmi) * 0.5
-    our, oui = (ci + cmi) * 0.5, (cmr - cr) * 0.5
-    wr, wi = (jnp.asarray(a, cr.dtype) for a in tw.rfft_split_twiddle_np(n))
-    ar = er + (our * wr - oui * wi)
-    ai = ei + (our * wi + oui * wr)
-    # A[n/2] = E[0] - O[0]; E[0], O[0] are exactly real (Cm[0] == C[0])
-    edge_r = er[..., :1] - our[..., :1]
-    return (jnp.concatenate([ar, edge_r], axis=-1),
-            jnp.concatenate([ai, jnp.zeros_like(edge_r)], axis=-1))
+    with scopes.scope(scopes.COMBINE):
+        if dtype is not None:
+            x = x.astype(dtype)
+        xe, xo = x[..., 0::2], x[..., 1::2]
+    cr, ci = cfft(xe, xo)
+    with scopes.scope(scopes.COMBINE):
+        # Cm[k] = C[(h - k) mod h], read as a gather with constant
+        # indices: XLA:TPU computed a reverse of the pencil axis fused
+        # into the inverse's combine wrongly (see irfft_pencil), and of
+        # the forms measured on TPU v5e the gather is exact and the
+        # fastest here
+        mirror = (h - np.arange(h)) % h
+        cmr = jnp.take(cr, mirror, axis=-1)
+        cmi = jnp.take(ci, mirror, axis=-1)
+        er, ei = (cr + cmr) * 0.5, (ci - cmi) * 0.5
+        our, oui = (ci + cmi) * 0.5, (cmr - cr) * 0.5
+        wr, wi = (jnp.asarray(a, cr.dtype)
+                  for a in tw.rfft_split_twiddle_np(n))
+        ar = er + (our * wr - oui * wi)
+        ai = ei + (our * wi + oui * wr)
+        # A[n/2] = E[0] - O[0]; E[0], O[0] are exactly real (Cm[0] == C[0])
+        edge_r = er[..., :1] - our[..., :1]
+        return (jnp.concatenate([ar, edge_r], axis=-1),
+                jnp.concatenate([ai, jnp.zeros_like(edge_r)], axis=-1))
 
 
 def irfft_pencil(re: jnp.ndarray, im: jnp.ndarray, *, cifft) -> jnp.ndarray:
@@ -352,24 +359,28 @@ def irfft_pencil(re: jnp.ndarray, im: jnp.ndarray, *, cifft) -> jnp.ndarray:
     n = 2 * h
     if h < 1:
         raise ValueError(f"irfft pencil needs >= 2 spectrum bins, got {nh}")
-    ar, ai = re[..., :h], im[..., :h]
-    # Am[k] = A[h - k], k in [0, h). The barrier keeps the reverse out of
-    # the combine's fusion: fused into it at large batch (>= 2^16
-    # pencils of 257 or 513 bins, as in the per-device (256, 256, 258)
-    # of the sharded 512^3 irfft), XLA:TPU computed it wrongly. (A
-    # gather, as in rfft_pencil, is exact there too, but on XLA:CPU it
-    # fuses differently per batch shape and batched executions stop
-    # being bit-identical to per-request ones.)
-    amr, ami = dbarrier((jnp.flip(re[..., 1:], -1),
-                         jnp.flip(im[..., 1:], -1)))
-    er, ei = (ar + amr) * 0.5, (ai - ami) * 0.5
-    # w^k O[k] = (A[k] - conj(Am[k])) / 2, then rotate by w^{-k}
-    tr, ti = (ar - amr) * 0.5, (ai + ami) * 0.5
-    wr, wi = (jnp.asarray(a, ar.dtype) for a in tw.rfft_split_twiddle_np(n))
-    our = tr * wr + ti * wi
-    oui = ti * wr - tr * wi
-    cr, ci = cifft(er - oui, ei + our)
-    return jnp.stack([cr, ci], axis=-1).reshape(re.shape[:-1] + (n,))
+    with scopes.scope(scopes.COMBINE):
+        ar, ai = re[..., :h], im[..., :h]
+        # Am[k] = A[h - k], k in [0, h). The barrier keeps the reverse
+        # out of the combine's fusion: fused into it at large batch (>=
+        # 2^16 pencils of 257 or 513 bins, as in the per-device (256,
+        # 256, 258) of the sharded 512^3 irfft), XLA:TPU computed it
+        # wrongly. (A gather, as in rfft_pencil, is exact there too, but
+        # on XLA:CPU it fuses differently per batch shape and batched
+        # executions stop being bit-identical to per-request ones.)
+        amr, ami = dbarrier((jnp.flip(re[..., 1:], -1),
+                             jnp.flip(im[..., 1:], -1)))
+        er, ei = (ar + amr) * 0.5, (ai - ami) * 0.5
+        # w^k O[k] = (A[k] - conj(Am[k])) / 2, then rotate by w^{-k}
+        tr, ti = (ar - amr) * 0.5, (ai + ami) * 0.5
+        wr, wi = (jnp.asarray(a, ar.dtype)
+                  for a in tw.rfft_split_twiddle_np(n))
+        our = tr * wr + ti * wi
+        oui = ti * wr - tr * wi
+        zr, zi = er - oui, ei + our
+    cr, ci = cifft(zr, zi)
+    with scopes.scope(scopes.COMBINE):
+        return jnp.stack([cr, ci], axis=-1).reshape(re.shape[:-1] + (n,))
 
 
 def rfft_via(pencil_fn):

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import comm as commlib
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.core.plan import Layout, PencilPlan
 from repro.fft import large1d, methods, pencil
@@ -307,6 +308,13 @@ def _resolve_comm_1d(factors, axes, mesh_shape, comm, overlap_chunks, method,
     return comm, oc, method
 
 
+def _planar(x):
+    """The planar ``(re, im)`` of a complex array: the complex split at
+    a plan's boundary."""
+    with scopes.scope(scopes.BOUNDARY):
+        return x.real, x.imag
+
+
 class FFT:
     """A planned distributed FFT: build once, execute many times.
 
@@ -496,6 +504,10 @@ class FFT:
         return self._apply('inv', x)
 
     def _apply(self, direction, x):
+        with scopes.span(f'{scopes.APPLY}.{direction}'):
+            return self._dispatch(direction, x)
+
+    def _dispatch(self, direction, x):
         planar = isinstance(x, (tuple, list))
         if planar and self.real and direction == 'fwd':
             raise ValueError(
@@ -531,10 +543,12 @@ class FFT:
                 f"leading batch dim, got batch shape {batch_shape}")
         key = (direction, batch_shape, jnp.dtype(dtype).name, planar)
         fn = self._exec_cache.get(key)
-        if fn is None:
-            fn = self._build(direction, batch_shape, planar)
-            self._exec_cache[key] = fn
-        return fn(re, im) if planar else fn(x)
+        if fn is not None:
+            return fn(re, im) if planar else fn(x)
+        fn = self._build(direction, batch_shape, planar)
+        self._exec_cache[key] = fn
+        with scopes.span(scopes.COMPILE):
+            return fn(re, im) if planar else fn(x)
 
     def _raw(self, direction, batched):
         key = (direction, batched)
@@ -594,12 +608,14 @@ class FFT:
 
         def run_planar(re, im):
             if collapse:
-                re = re.reshape(flatb + in_core)
-                im = im.reshape(flatb + in_core)
+                with scopes.scope(scopes.BOUNDARY):
+                    re = re.reshape(flatb + in_core)
+                    im = im.reshape(flatb + in_core)
             yr, yi = raw(re, im)
             if collapse:
-                yr = yr.reshape(out_shape)
-                yi = yi.reshape(out_shape)
+                with scopes.scope(scopes.BOUNDARY):
+                    yr = yr.reshape(out_shape)
+                    yi = yi.reshape(out_shape)
             return yr, yi
 
         # donated inputs: same global shape/dtype in and out, so XLA
@@ -610,8 +626,9 @@ class FFT:
             return jax.jit(run_planar, donate_argnums=(0, 1) if dn else ())
 
         def run_complex(x):
-            yr, yi = run_planar(x.real, x.imag)
-            return jax.lax.complex(yr, yi)
+            yr, yi = run_planar(*_planar(x))
+            with scopes.scope(scopes.BOUNDARY):
+                return jax.lax.complex(yr, yi)
 
         return jax.jit(run_complex, donate_argnums=(0,) if dn else ())
 
@@ -642,33 +659,39 @@ class FFT:
 
             def run_fwd(x):
                 if collapse:
-                    x = x.reshape(flatb + self.shape)
+                    with scopes.scope(scopes.BOUNDARY):
+                        x = x.reshape(flatb + self.shape)
                 yr, yi = raw(x)
-                if nh_out != nh_pad:
-                    yr, yi = yr[..., :nh_out], yi[..., :nh_out]
-                if collapse:
-                    yr, yi = yr.reshape(out_shape), yi.reshape(out_shape)
-                return jax.lax.complex(yr, yi)
+                with scopes.scope(scopes.BOUNDARY):
+                    if nh_out != nh_pad:
+                        yr, yi = yr[..., :nh_out], yi[..., :nh_out]
+                    if collapse:
+                        yr, yi = yr.reshape(out_shape), yi.reshape(out_shape)
+                    return jax.lax.complex(yr, yi)
 
             return jax.jit(run_fwd, out_shardings=shard(self.out_layout))
 
         out_shape = batch_shape + self.shape
 
         def run_inv_planar(re, im):
-            if collapse:
-                re = re.reshape(flatb + self.spectrum_shape)
-                im = im.reshape(flatb + self.spectrum_shape)
-            if nh_out != nh_pad:
-                pw = [(0, 0)] * re.ndim
-                pw[-1] = (0, nh_pad - nh_out)
-                re, im = jnp.pad(re, pw), jnp.pad(im, pw)
+            with scopes.scope(scopes.BOUNDARY):
+                if collapse:
+                    re = re.reshape(flatb + self.spectrum_shape)
+                    im = im.reshape(flatb + self.spectrum_shape)
+                if nh_out != nh_pad:
+                    pw = [(0, 0)] * re.ndim
+                    pw[-1] = (0, nh_pad - nh_out)
+                    re, im = jnp.pad(re, pw), jnp.pad(im, pw)
             x = raw(re, im)
-            return x.reshape(out_shape) if collapse else x
+            if collapse:
+                with scopes.scope(scopes.BOUNDARY):
+                    return x.reshape(out_shape)
+            return x
 
         out_sh = shard(self.in_layout)
         if planar:
             return jax.jit(run_inv_planar, out_shardings=out_sh)
-        return jax.jit(lambda y: run_inv_planar(y.real, y.imag),
+        return jax.jit(lambda y: run_inv_planar(*_planar(y)),
                        out_shardings=out_sh)
 
     def _build_real_1d(self, direction, raw, batch_shape, flatb, planar,
@@ -692,41 +715,51 @@ class FFT:
             out_shape = batch_shape + (nh,)
 
             def run_fwd(x):
-                x = x.reshape(flatb + (n1, n2))
+                with scopes.scope(scopes.BOUNDARY):
+                    x = x.reshape(flatb + (n1, n2))
                 dr, di = raw(x)
-                dr, di = dr[..., :nh1, :], di[..., :nh1, :]
-                # rows n1//2+1 .. n1-1 of the full plane, Hermitian-mirrored
-                br = jnp.flip(jnp.flip(dr[..., 1:n1 // 2, :], -2), -1)
-                bi = -jnp.flip(jnp.flip(di[..., 1:n1 // 2, :], -2), -1)
-                fr = jnp.concatenate([dr, br], -2)
-                fi = jnp.concatenate([di, bi], -2)
-                yr = jnp.swapaxes(fr, -1, -2).reshape(flatb + (n,))[..., :nh]
-                yi = jnp.swapaxes(fi, -1, -2).reshape(flatb + (n,))[..., :nh]
-                return jax.lax.complex(yr.reshape(out_shape),
-                                       yi.reshape(out_shape))
+                with scopes.scope(scopes.BOUNDARY):
+                    dr, di = dr[..., :nh1, :], di[..., :nh1, :]
+                    # rows n1//2+1 .. n1-1 of the full plane,
+                    # Hermitian-mirrored
+                    br = jnp.flip(jnp.flip(dr[..., 1:n1 // 2, :], -2), -1)
+                    bi = -jnp.flip(jnp.flip(di[..., 1:n1 // 2, :], -2), -1)
+                    fr = jnp.concatenate([dr, br], -2)
+                    fi = jnp.concatenate([di, bi], -2)
+                    yr = jnp.swapaxes(fr, -1, -2).reshape(
+                        flatb + (n,))[..., :nh]
+                    yi = jnp.swapaxes(fi, -1, -2).reshape(
+                        flatb + (n,))[..., :nh]
+                    return jax.lax.complex(yr.reshape(out_shape),
+                                           yi.reshape(out_shape))
 
             return jax.jit(run_fwd, out_shardings=shard(self.out_layout))
 
         out_shape = batch_shape + (n,)
 
         def run_inv_planar(re, im):
-            re = re.reshape(flatb + (nh,))
-            im = im.reshape(flatb + (nh,))
-            # Hermitian-extend to the full spectrum, view as D rows
-            fr = jnp.concatenate([re, jnp.flip(re[..., 1:n // 2], -1)], -1)
-            fi = jnp.concatenate([im, -jnp.flip(im[..., 1:n // 2], -1)], -1)
-            dr = jnp.swapaxes(fr.reshape(flatb + (n2, n1)), -1, -2)
-            di = jnp.swapaxes(fi.reshape(flatb + (n2, n1)), -1, -2)
-            dr, di = dr[..., :nh1, :], di[..., :nh1, :]
-            pw = [(0, 0)] * dr.ndim
-            pw[-2] = (0, nh1p - nh1)
-            x = raw(jnp.pad(dr, pw), jnp.pad(di, pw))
-            return x.reshape(out_shape)
+            with scopes.scope(scopes.BOUNDARY):
+                re = re.reshape(flatb + (nh,))
+                im = im.reshape(flatb + (nh,))
+                # Hermitian-extend to the full spectrum, view as D rows
+                fr = jnp.concatenate([re, jnp.flip(re[..., 1:n // 2], -1)],
+                                     -1)
+                fi = jnp.concatenate([im, -jnp.flip(im[..., 1:n // 2], -1)],
+                                     -1)
+                dr = jnp.swapaxes(fr.reshape(flatb + (n2, n1)), -1, -2)
+                di = jnp.swapaxes(fi.reshape(flatb + (n2, n1)), -1, -2)
+                dr, di = dr[..., :nh1, :], di[..., :nh1, :]
+                pw = [(0, 0)] * dr.ndim
+                pw[-2] = (0, nh1p - nh1)
+                dr, di = jnp.pad(dr, pw), jnp.pad(di, pw)
+            x = raw(dr, di)
+            with scopes.scope(scopes.BOUNDARY):
+                return x.reshape(out_shape)
 
         out_sh = shard(self.in_layout)
         if planar:
             return jax.jit(run_inv_planar, out_shardings=out_sh)
-        return jax.jit(lambda y: run_inv_planar(y.real, y.imag),
+        return jax.jit(lambda y: run_inv_planar(*_planar(y)),
                        out_shardings=out_sh)
 
     # -- cache sizing hooks (serve-engine plan cache accounting) ------------

@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import comm as commlib
 from repro.comm import overlap as ov
+from repro.core import scopes
 from repro.fft import methods
 
 
@@ -102,52 +103,67 @@ def make_fft1d_large(n1: int, n2: int, plan_mesh, mesh_axes=('x', 'y'), *,
         return wr, wi
 
     def body(ar, ai):
-        # in: (n1/p, n2) rows-sharded. swap -> (n1, n2/p)
-        ar = wswap(ar, off + 0, off + 1)
-        ai = wswap(ai, off + 0, off + 1)
-        if fused:
-            # fused superstep: columns DFT over k1 + inter-factor
-            # twiddle + orientation restore in ONE pass — the rotation
-            # and both moveaxis passes around the column FFT fold into
-            # the FFT's own transposed emit (in-kernel on the Pallas
-            # tier), so the swap back reads pre-rotated data
-            wr, wi = _twiddle(transposed=True)           # (m2, n1)
-            ar, ai = methods.apply_fused(
-                jnp.swapaxes(ar, off + 0, off + 1),
-                jnp.swapaxes(ai, off + 0, off + 1),
-                wr=wr, wi=wi, inverse=inverse, method=method,
-                compute_dtype=compute_dtype, kernel=kern)
-        else:
-            # columns DFT over k1 (local axis 0)
-            ar, ai = methods.apply(ar, ai, axis=off + 0, inverse=inverse,
-                                   method=method, compute_dtype=compute_dtype,
-                                   kernel=kern)
-            wr, wi = _twiddle(transposed=False)          # (n1, m2)
-            ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
-        # swap back -> (n1/p, n2); rows DFT over k2 (local axis 1)
-        ar = wswap(ar, off + 1, off + 0)
-        ai = wswap(ai, off + 1, off + 0)
-        if natural_order and fused:
-            # rows DFT with transposed emit: the fused op's (j2, j1)
-            # output IS the natural-order local transpose, so only the
-            # ownership exchange remains (at the permuted positions)
-            ar, ai = methods.apply_fused(ar, ai, inverse=inverse,
-                                         method=method,
-                                         compute_dtype=compute_dtype,
-                                         kernel=kern)
-            ar = wswap(ar, off + 1, off + 0)             # -> (n2/p, n1)
-            ai = wswap(ai, off + 1, off + 0)
-            return ar, ai
-        ar, ai = methods.apply(ar, ai, axis=off + 1, inverse=inverse,
-                               method=method, compute_dtype=compute_dtype,
-                               kernel=kern)
-        if natural_order:
-            # content transpose D -> D.T: exchange ownership then local T
+        # superstep 0 — in: (n1/p, n2) rows-sharded. swap -> (n1, n2/p)
+        with scopes.scope(scopes.step(0)):
             ar = wswap(ar, off + 0, off + 1)
             ai = wswap(ai, off + 0, off + 1)
-            ar = ar.swapaxes(off + 0, off + 1)          # (n2/p, n1)
-            ai = ai.swapaxes(off + 0, off + 1)
-        return ar, ai
+            if fused:
+                # fused superstep: columns DFT over k1 + inter-factor
+                # twiddle + orientation restore in ONE pass — the
+                # rotation and both moveaxis passes around the column
+                # FFT fold into the FFT's own transposed emit (in-kernel
+                # on the Pallas tier), so the swap back reads pre-rotated
+                # data
+                with scopes.scope(scopes.PASS):
+                    wr, wi = _twiddle(transposed=True)   # (m2, n1)
+                with scopes.scope(scopes.RELAYOUT):
+                    tr = jnp.swapaxes(ar, off + 0, off + 1)
+                    ti = jnp.swapaxes(ai, off + 0, off + 1)
+                ar, ai = methods.apply_fused(
+                    tr, ti, wr=wr, wi=wi, inverse=inverse, method=method,
+                    compute_dtype=compute_dtype, kernel=kern)
+            else:
+                # columns DFT over k1 (local axis 0)
+                ar, ai = methods.apply(ar, ai, axis=off + 0,
+                                       inverse=inverse, method=method,
+                                       compute_dtype=compute_dtype,
+                                       kernel=kern)
+                with scopes.scope(scopes.PASS):
+                    wr, wi = _twiddle(transposed=False)  # (n1, m2)
+                    ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
+        # superstep 1 — swap back -> (n1/p, n2); rows DFT over k2
+        with scopes.scope(scopes.step(1)):
+            ar = wswap(ar, off + 1, off + 0)
+            ai = wswap(ai, off + 1, off + 0)
+            if natural_order and fused:
+                # rows DFT with transposed emit: the fused op's (j2, j1)
+                # output IS the natural-order local transpose, so only
+                # the ownership exchange remains (at the permuted
+                # positions)
+                ar, ai = methods.apply_fused(ar, ai, inverse=inverse,
+                                             method=method,
+                                             compute_dtype=compute_dtype,
+                                             kernel=kern)
+            else:
+                ar, ai = methods.apply(ar, ai, axis=off + 1,
+                                       inverse=inverse, method=method,
+                                       compute_dtype=compute_dtype,
+                                       kernel=kern)
+        if not natural_order:
+            return ar, ai
+        # superstep 2 — content transpose D -> D.T: exchange ownership
+        # (then the local transpose, unless the fused emit made it)
+        with scopes.scope(scopes.step(2)):
+            if fused:
+                ar = wswap(ar, off + 1, off + 0)         # -> (n2/p, n1)
+                ai = wswap(ai, off + 1, off + 0)
+                return ar, ai
+            ar = wswap(ar, off + 0, off + 1)
+            ai = wswap(ai, off + 0, off + 1)
+            with scopes.scope(scopes.RELAYOUT):
+                ar = ar.swapaxes(off + 0, off + 1)      # (n2/p, n1)
+                ai = ai.swapaxes(off + 0, off + 1)
+            return ar, ai
 
     def local(ar, ai):
         # the whole four-step is batch-independent: pipelining it over
@@ -218,23 +234,30 @@ def _real_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
         return (wr, -wi) if conj else (wr, wi)
 
     def body_fwd(x, off):
-        # in: (n1/p, n2) real rows-sharded; swap moves ONE real array
-        x = wswap(x, off + 0, off + 1)
-        # r2c column DFT over k1 -> (nh1, n2/p), padded rows
-        ar, ai = methods.apply_real(x, axis=off + 0, method=method,
-                                    compute_dtype=compute_dtype)
-        if nh1p != nh1:
-            pw = [(0, 0)] * ar.ndim
-            pw[off + 0] = (0, nh1p - nh1)
-            ar, ai = jnp.pad(ar, pw), jnp.pad(ai, pw)
-        wr, wi = _twiddle(conj=False)
-        ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
-        # swap back -> (nh1p/p, n2); row DFT over k2
-        ar = wswap(ar, off + 1, off + 0)
-        ai = wswap(ai, off + 1, off + 0)
-        ar, ai = methods.apply(ar, ai, axis=off + 1, method=method,
-                               compute_dtype=compute_dtype, kernel=kern)
-        return _canon(*pin_rounding(ar, ai))
+        with scopes.scope(scopes.step(0)):
+            # in: (n1/p, n2) real rows-sharded; swap moves ONE real array
+            x = wswap(x, off + 0, off + 1)
+            # r2c column DFT over k1 -> (nh1, n2/p), padded rows
+            ar, ai = methods.apply_real(x, axis=off + 0, method=method,
+                                        compute_dtype=compute_dtype)
+            if nh1p != nh1:
+                pw = [(0, 0)] * ar.ndim
+                pw[off + 0] = (0, nh1p - nh1)
+                with scopes.scope(scopes.RELAYOUT):
+                    ar, ai = jnp.pad(ar, pw), jnp.pad(ai, pw)
+            with scopes.scope(scopes.PASS):
+                wr, wi = _twiddle(conj=False)
+                ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
+        with scopes.scope(scopes.step(1)):
+            # swap back -> (nh1p/p, n2); row DFT over k2
+            ar = wswap(ar, off + 1, off + 0)
+            ai = wswap(ai, off + 1, off + 0)
+            ar, ai = methods.apply(ar, ai, axis=off + 1, method=method,
+                                   compute_dtype=compute_dtype, kernel=kern)
+            with scopes.scope(scopes.PASS):
+                ar, ai = pin_rounding(ar, ai)
+            with scopes.scope(scopes.COMBINE):
+                return _canon(ar, ai)
 
     def _canon(ar, ai):
         # Hermitian-canonicalize rows 0 and n1//2 (see the factory
@@ -259,23 +282,29 @@ def _real_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
         return ar, ai
 
     def body_inv(ar, ai, off):
-        # in: (nh1p/p, n2) planar rows-sharded; row IDFT over j2
-        ar, ai = pin_rounding(ar, ai)
-        ar, ai = methods.apply(ar, ai, axis=off + 1, inverse=True,
-                               method=method, compute_dtype=compute_dtype,
-                               kernel=kern)
-        # swap -> (nh1p, n2/p); conjugate twiddle
-        ar = wswap(ar, off + 0, off + 1)
-        ai = wswap(ai, off + 0, off + 1)
-        wr, wi = _twiddle(conj=True)
-        ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
-        # drop pad rows, c2r column IDFT -> (n1, n2/p) real
-        ar = lax.slice_in_dim(ar, 0, nh1, axis=off + 0)
-        ai = lax.slice_in_dim(ai, 0, nh1, axis=off + 0)
-        x = methods.apply_real(ar, ai, axis=off + 0, inverse=True,
-                               method=method, compute_dtype=compute_dtype)
-        # swap the real array back to rows-sharded
-        return wswap(x, off + 1, off + 0)
+        with scopes.scope(scopes.step(0)):
+            # in: (nh1p/p, n2) planar rows-sharded; row IDFT over j2
+            with scopes.scope(scopes.PASS):
+                ar, ai = pin_rounding(ar, ai)
+            ar, ai = methods.apply(ar, ai, axis=off + 1, inverse=True,
+                                   method=method, compute_dtype=compute_dtype,
+                                   kernel=kern)
+            # swap -> (nh1p, n2/p)
+            ar = wswap(ar, off + 0, off + 1)
+            ai = wswap(ai, off + 0, off + 1)
+        with scopes.scope(scopes.step(1)):
+            # conjugate twiddle
+            with scopes.scope(scopes.PASS):
+                wr, wi = _twiddle(conj=True)
+                ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
+            # drop pad rows, c2r column IDFT -> (n1, n2/p) real
+            with scopes.scope(scopes.RELAYOUT):
+                ar = lax.slice_in_dim(ar, 0, nh1, axis=off + 0)
+                ai = lax.slice_in_dim(ai, 0, nh1, axis=off + 0)
+            x = methods.apply_real(ar, ai, axis=off + 0, inverse=True,
+                                   method=method, compute_dtype=compute_dtype)
+            # swap the real array back to rows-sharded
+            return wswap(x, off + 1, off + 0)
 
     return body_fwd, body_inv, nh1, nh1p
 
@@ -308,46 +337,55 @@ def _complex_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
         return (wr, -wi) if conj else (wr, wi)
 
     def body_fwd(ar, ai, off):
-        # in: (n1/p, n2) rows-sharded. swap -> (n1, n2/p)
-        ar = wswap(ar, off + 0, off + 1)
-        ai = wswap(ai, off + 0, off + 1)
-        if fused:
-            wr, wi = _twiddle(transposed=True, conj=False)   # (m2, n1)
-            ar, ai = methods.apply_fused(
-                jnp.swapaxes(ar, off + 0, off + 1),
-                jnp.swapaxes(ai, off + 0, off + 1),
-                wr=wr, wi=wi, inverse=False, method=method,
-                compute_dtype=compute_dtype, kernel=kern)
-        else:
-            ar, ai = methods.apply(ar, ai, axis=off + 0, inverse=False,
-                                   method=method, compute_dtype=compute_dtype,
-                                   kernel=kern)
-            wr, wi = _twiddle(transposed=False, conj=False)  # (n1, m2)
-            ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
-        # swap back -> (n1/p, n2); rows DFT over k2 -> D[j1, j2]
-        ar = wswap(ar, off + 1, off + 0)
-        ai = wswap(ai, off + 1, off + 0)
-        return methods.apply(ar, ai, axis=off + 1, inverse=False,
-                             method=method, compute_dtype=compute_dtype,
-                             kernel=kern)
+        with scopes.scope(scopes.step(0)):
+            # in: (n1/p, n2) rows-sharded. swap -> (n1, n2/p)
+            ar = wswap(ar, off + 0, off + 1)
+            ai = wswap(ai, off + 0, off + 1)
+            if fused:
+                with scopes.scope(scopes.PASS):
+                    wr, wi = _twiddle(transposed=True, conj=False)
+                with scopes.scope(scopes.RELAYOUT):
+                    tr = jnp.swapaxes(ar, off + 0, off + 1)
+                    ti = jnp.swapaxes(ai, off + 0, off + 1)
+                ar, ai = methods.apply_fused(
+                    tr, ti, wr=wr, wi=wi, inverse=False, method=method,
+                    compute_dtype=compute_dtype, kernel=kern)
+            else:
+                ar, ai = methods.apply(ar, ai, axis=off + 0, inverse=False,
+                                       method=method,
+                                       compute_dtype=compute_dtype,
+                                       kernel=kern)
+                with scopes.scope(scopes.PASS):
+                    wr, wi = _twiddle(transposed=False, conj=False)
+                    ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
+        with scopes.scope(scopes.step(1)):
+            # swap back -> (n1/p, n2); rows DFT over k2 -> D[j1, j2]
+            ar = wswap(ar, off + 1, off + 0)
+            ai = wswap(ai, off + 1, off + 0)
+            return methods.apply(ar, ai, axis=off + 1, inverse=False,
+                                 method=method, compute_dtype=compute_dtype,
+                                 kernel=kern)
 
     def body_inv(ar, ai, off):
         # exact mirror: rows IDFT over j2, swap, conjugate twiddle,
         # columns IDFT over j1, swap back — 1/n2 then 1/n1 scaling
         # matches the natural-order inverse's ifft pair
-        ar, ai = methods.apply(ar, ai, axis=off + 1, inverse=True,
-                               method=method, compute_dtype=compute_dtype,
-                               kernel=kern)
-        ar = wswap(ar, off + 0, off + 1)
-        ai = wswap(ai, off + 0, off + 1)
-        wr, wi = _twiddle(transposed=False, conj=True)
-        ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
-        ar, ai = methods.apply(ar, ai, axis=off + 0, inverse=True,
-                               method=method, compute_dtype=compute_dtype,
-                               kernel=kern)
-        ar = wswap(ar, off + 1, off + 0)
-        ai = wswap(ai, off + 1, off + 0)
-        return ar, ai
+        with scopes.scope(scopes.step(0)):
+            ar, ai = methods.apply(ar, ai, axis=off + 1, inverse=True,
+                                   method=method, compute_dtype=compute_dtype,
+                                   kernel=kern)
+            ar = wswap(ar, off + 0, off + 1)
+            ai = wswap(ai, off + 0, off + 1)
+        with scopes.scope(scopes.step(1)):
+            with scopes.scope(scopes.PASS):
+                wr, wi = _twiddle(transposed=False, conj=True)
+                ar, ai = ar * wr - ai * wi, ar * wi + ai * wr
+            ar, ai = methods.apply(ar, ai, axis=off + 0, inverse=True,
+                                   method=method, compute_dtype=compute_dtype,
+                                   kernel=kern)
+            ar = wswap(ar, off + 1, off + 0)
+            ai = wswap(ai, off + 1, off + 0)
+            return ar, ai
 
     return body_fwd, body_inv
 

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fft1d as _f1
+from repro.core import scopes
 from repro.core import twiddle as tw
 
 Planar = Tuple[jnp.ndarray, jnp.ndarray]
@@ -236,24 +237,38 @@ def apply(re: jnp.ndarray, im: jnp.ndarray, *, axis: int = -1,
             f"method {m.name!r} requires a power-of-two pencil length, "
             f"got {n} (use method='direct' or 'auto')")
     last = axis == re.ndim - 1
-    if resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m,
-                      n=n) == 'pallas':
-        kfn = m.kernel_for()
+    with scopes.scope(scopes.PASS):
+        if resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m,
+                          n=n) == 'pallas':
+            kfn = m.kernel_for()
+            if not last:
+                re, im = _to_last(axis, re, im)
+            yr, yi = kfn(re, im, inverse=inverse, interpret=interpret)
+            if not last:
+                yr, yi = _from_last(axis, yr, yi)
+            return yr, yi
+        if m.axis_fn is not None and not last:
+            return m.axis_fn(re, im, axis, inverse=inverse,
+                             compute_dtype=compute_dtype)
         if not last:
-            re, im = jnp.moveaxis(re, axis, -1), jnp.moveaxis(im, axis, -1)
-        yr, yi = kfn(re, im, inverse=inverse, interpret=interpret)
+            re, im = _to_last(axis, re, im)
+        yr, yi = m.pencil_fn(re, im, inverse=inverse,
+                             compute_dtype=compute_dtype)
         if not last:
-            yr, yi = jnp.moveaxis(yr, -1, axis), jnp.moveaxis(yi, -1, axis)
+            yr, yi = _from_last(axis, yr, yi)
         return yr, yi
-    if m.axis_fn is not None and not last:
-        return m.axis_fn(re, im, axis, inverse=inverse,
-                         compute_dtype=compute_dtype)
-    if not last:
-        re, im = jnp.moveaxis(re, axis, -1), jnp.moveaxis(im, axis, -1)
-    yr, yi = m.pencil_fn(re, im, inverse=inverse, compute_dtype=compute_dtype)
-    if not last:
-        yr, yi = jnp.moveaxis(yr, -1, axis), jnp.moveaxis(yi, -1, axis)
-    return yr, yi
+
+
+def _to_last(axis: int, *arrays):
+    """``arrays`` with ``axis`` moved last, under the relayout scope."""
+    with scopes.scope(scopes.RELAYOUT):
+        return tuple(jnp.moveaxis(a, axis, -1) for a in arrays)
+
+
+def _from_last(axis: int, *arrays):
+    """Inverse of :func:`_to_last`."""
+    with scopes.scope(scopes.RELAYOUT):
+        return tuple(jnp.moveaxis(a, -1, axis) for a in arrays)
 
 
 def apply_real(x: jnp.ndarray, im: Optional[jnp.ndarray] = None, *,
@@ -287,17 +302,18 @@ def apply_real(x: jnp.ndarray, im: Optional[jnp.ndarray] = None, *,
     if m.real_fn is None:
         raise ValueError(f"method {m.name!r} has no real-input form")
     last = axis == x.ndim - 1
-    if not last:
-        x = jnp.moveaxis(x, axis, -1)
-        if im is not None:
-            im = jnp.moveaxis(im, axis, -1)
-    if inverse:
-        y = m.real_fn(x, im, inverse=True, compute_dtype=compute_dtype)
-        return y if last else jnp.moveaxis(y, -1, axis)
-    yr, yi = m.real_fn(x, compute_dtype=compute_dtype)
-    if not last:
-        yr, yi = jnp.moveaxis(yr, -1, axis), jnp.moveaxis(yi, -1, axis)
-    return yr, yi
+    with scopes.scope(scopes.PASS):
+        if inverse:
+            if not last:
+                x, im = _to_last(axis, x, im)
+            y = m.real_fn(x, im, inverse=True, compute_dtype=compute_dtype)
+            return y if last else _from_last(axis, y)[0]
+        if not last:
+            (x,) = _to_last(axis, x)
+        yr, yi = m.real_fn(x, compute_dtype=compute_dtype)
+        if not last:
+            yr, yi = _from_last(axis, yr, yi)
+        return yr, yi
 
 
 def apply_block(x: jnp.ndarray, *, axis: int, inverse: bool = False,
@@ -316,15 +332,16 @@ def apply_block(x: jnp.ndarray, *, axis: int, inverse: bool = False,
             f"method 'block' requires a power-of-two pencil length, got {n}")
     tier = resolve_kernel(_merge_kernel_arg(kernel, use_kernel),
                           _REGISTRY.get('block'), n=n)
-    if tier == 'pallas':
-        from repro.kernels import fft_block as _kb
-        last = axis == x.ndim - 1
-        if not last:
-            x = jnp.moveaxis(x, axis, -1)
-        y = _kb.fft_block(x, inverse=inverse, interpret=interpret)
-        return y if last else jnp.moveaxis(y, -1, axis)
-    return _f1.fft_four_step_block(x, axis, inverse=inverse,
-                                   compute_dtype=compute_dtype)
+    with scopes.scope(scopes.PASS):
+        if tier == 'pallas':
+            from repro.kernels import fft_block as _kb
+            last = axis == x.ndim - 1
+            if not last:
+                (x,) = _to_last(axis, x)
+            y = _kb.fft_block(x, inverse=inverse, interpret=interpret)
+            return y if last else _from_last(axis, y)[0]
+        return _f1.fft_four_step_block(x, axis, inverse=inverse,
+                                       compute_dtype=compute_dtype)
 
 
 def apply_fused(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
@@ -355,18 +372,21 @@ def apply_fused(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
             f"method {m.name!r} requires a power-of-two pencil length, "
             f"got {n} (use method='direct' or 'auto')")
     tier = resolve_kernel(_merge_kernel_arg(kernel, use_kernel), m, n=n)
-    if tier == 'pallas':
-        if m.name == 'stockham':
-            from repro.kernels import fft_fused as _kf
-            return _kf.fft_twiddle_transpose(
-                re, im, wr, wi, inverse=inverse, interpret=interpret)
-        yr, yi = m.kernel_for()(re, im, inverse=inverse, interpret=interpret)
-        if wr is not None:
-            yr, yi = yr * wr - yi * wi, yr * wi + yi * wr
-        return jnp.swapaxes(yr, -1, -2), jnp.swapaxes(yi, -1, -2)
-    return _f1.fft_twiddle_transpose(
-        re, im, wr, wi, inverse=inverse, fft_fn=m.pencil_fn,
-        compute_dtype=compute_dtype)
+    with scopes.scope(scopes.PASS):
+        if tier == 'pallas':
+            if m.name == 'stockham':
+                from repro.kernels import fft_fused as _kf
+                return _kf.fft_twiddle_transpose(
+                    re, im, wr, wi, inverse=inverse, interpret=interpret)
+            yr, yi = m.kernel_for()(re, im, inverse=inverse,
+                                    interpret=interpret)
+            if wr is not None:
+                yr, yi = yr * wr - yi * wi, yr * wi + yi * wr
+            with scopes.scope(scopes.RELAYOUT):
+                return jnp.swapaxes(yr, -1, -2), jnp.swapaxes(yi, -1, -2)
+        return _f1.fft_twiddle_transpose(
+            re, im, wr, wi, inverse=inverse, fft_fn=m.pencil_fn,
+            compute_dtype=compute_dtype)
 
 
 # ---------------------------------------------------------------------------

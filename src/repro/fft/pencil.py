@@ -25,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from repro import comm
 from repro.comm import overlap as ov
 from repro.core import plan as planlib
+from repro.core import scopes
 from repro.core.plan import Layout, PencilPlan
 from repro.fft import methods
 
@@ -138,6 +139,22 @@ def inverse_schedule(layout: Layout,
     return tuple(steps), layout
 
 
+def superstep_ids(steps) -> Tuple[int, ...]:
+    """The superstep each schedule entry belongs to, counted from 0: an
+    axis pass opens a superstep and the swap right after it belongs to
+    it; a swap that follows no pass (a ``restore_layout`` rotation) is a
+    superstep of its own. Names the ``fft.step<i>`` device scopes."""
+    ids: List[int] = []
+    prev = None
+    for st in steps:
+        if not (st[0] == 'swap' and prev == 'fft'):
+            ids.append(ids[-1] + 1 if ids else 0)
+        else:
+            ids.append(ids[-1])
+        prev = st[0]
+    return tuple(ids)
+
+
 # ---------------------------------------------------------------------------
 # Half-spectrum extent bookkeeping (real plans)
 # ---------------------------------------------------------------------------
@@ -212,8 +229,9 @@ def _fused_pair(re, im, *, a: int, s: int, mesh_axis, inverse: bool,
     XLA op. Pure positional rearrangement around identical float ops, so
     outputs are bit-identical to the unfused path."""
     nd = re.ndim
-    re1 = jnp.moveaxis(re, a, -1)
-    im1 = jnp.moveaxis(im, a, -1)
+    with scopes.scope(scopes.RELAYOUT):
+        re1 = jnp.moveaxis(re, a, -1)
+        im1 = jnp.moveaxis(im, a, -1)
     fr, fi = methods.apply_fused(re1, im1, inverse=inverse,
                                  method=plan.method,
                                  compute_dtype=plan.compute_dtype,
@@ -231,79 +249,86 @@ def _fused_pair(re, im, *, a: int, s: int, mesh_axis, inverse: bool,
     inv = [0] * nd
     for i2, p in enumerate(order):
         inv[p] = i2
-    return jnp.transpose(fr, inv), jnp.transpose(fi, inv)
+    with scopes.scope(scopes.RELAYOUT):
+        return jnp.transpose(fr, inv), jnp.transpose(fi, inv)
 
 
 def _execute(re, im, layout: Layout, steps, *, inverse: bool, plan: PencilPlan,
-             batch_ndim: int, overlap_chunks: int,
-             fused: bool = True) -> Planar:
+             batch_ndim: int, overlap_chunks: int, fused: bool = True,
+             ids: Optional[Sequence[int]] = None) -> Planar:
     """Run fft/swap steps, threading the layout. When overlap_chunks > 1
     each (fft, swap) pair is pipelined (via repro.comm.overlap) over
     chunks of a free local axis so compute of chunk i+1 overlaps the
     collective of chunk i (beyond-paper); serial (fft, swap) pairs run
     as one fused twiddle+transpose superstep when ``fused``; swaps
-    dispatch through the plan's registered comm strategy."""
+    dispatch through the plan's registered comm strategy. Each superstep
+    runs under its ``fft.step<i>`` scope, ``i`` from ``ids`` (default:
+    :func:`superstep_ids` of ``steps``; a caller running a slice of a
+    schedule passes the slice of the whole schedule's ids)."""
     off = batch_ndim
     lay = layout
     strategy = comm.resolve(plan.comm)
     wire = plan.wire_dtype
+    ids = superstep_ids(steps) if ids is None else ids
     i = 0
     while i < len(steps):
         step = steps[i]
         nxt = steps[i + 1] if i + 1 < len(steps) else None
-        if (overlap_chunks > 1 and step[0] == 'fft' and nxt is not None
-                and nxt[0] == 'swap'):
-            mem = step[1]
-            _, mesh_axis, mem_pos = nxt
-            sp = planlib.owner_pos(lay, mesh_axis)
-            # chunk axis: any local axis — leading batch axes included,
-            # which is what pipelines a coalesced request batch — that
-            # is neither the fft axis nor the swap axes; fall back to
-            # no overlap if none exists.
-            ck = ov.pick_chunk_axis(re.shape,
-                                    (off + mem, off + mem_pos, off + sp),
-                                    overlap_chunks)
-            if ck is not None:
-                re, im = ov.overlapped_fft_swap(
-                    re, im,
-                    fft_fn=lambda r, i_, m=mem: _fft_along(
-                        r, i_, off + m, inverse=inverse, plan=plan),
-                    swap_fn=lambda a, ma=mesh_axis, s=sp, mp=mem_pos:
-                        strategy.swap_axes(a, ma, shard_pos=off + s,
-                                           mem_pos=off + mp),
-                    chunk_axis=ck, n_chunks=overlap_chunks,
-                    wire_dtype=wire)
-                lay = planlib.swap(lay, mesh_axis, mem_pos)
+        with scopes.scope(scopes.step(ids[i])):
+            if (overlap_chunks > 1 and step[0] == 'fft' and nxt is not None
+                    and nxt[0] == 'swap'):
+                mem = step[1]
+                _, mesh_axis, mem_pos = nxt
+                sp = planlib.owner_pos(lay, mesh_axis)
+                # chunk axis: any local axis — leading batch axes included,
+                # which is what pipelines a coalesced request batch — that
+                # is neither the fft axis nor the swap axes; fall back to
+                # no overlap if none exists.
+                ck = ov.pick_chunk_axis(re.shape,
+                                        (off + mem, off + mem_pos, off + sp),
+                                        overlap_chunks)
+                if ck is not None:
+                    re, im = ov.overlapped_fft_swap(
+                        re, im,
+                        fft_fn=lambda r, i_, m=mem: _fft_along(
+                            r, i_, off + m, inverse=inverse, plan=plan),
+                        swap_fn=lambda a, ma=mesh_axis, s=sp, mp=mem_pos:
+                            strategy.swap_axes(a, ma, shard_pos=off + s,
+                                               mem_pos=off + mp),
+                        chunk_axis=ck, n_chunks=overlap_chunks,
+                        wire_dtype=wire)
+                    lay = planlib.swap(lay, mesh_axis, mem_pos)
+                    i += 2
+                    continue
+            if (fused and step[0] == 'fft' and nxt is not None
+                    and nxt[0] == 'swap' and nxt[2] == step[1]
+                    and re.ndim >= 2):
+                # serial fused superstep: the swap reads the fft axis it is
+                # about to split (mem_pos == the just-transformed axis — the
+                # schedule invariant in both directions)
+                _, mesh_axis, _ = nxt
+                re, im = _fused_pair(
+                    re, im, a=off + step[1],
+                    s=off + planlib.owner_pos(lay, mesh_axis),
+                    mesh_axis=mesh_axis, inverse=inverse, plan=plan,
+                    strategy=strategy, wire=wire)
+                lay = planlib.swap(lay, mesh_axis, nxt[2])
                 i += 2
                 continue
-        if (fused and step[0] == 'fft' and nxt is not None
-                and nxt[0] == 'swap' and nxt[2] == step[1]
-                and re.ndim >= 2):
-            # serial fused superstep: the swap reads the fft axis it is
-            # about to split (mem_pos == the just-transformed axis — the
-            # schedule invariant in both directions)
-            _, mesh_axis, _ = nxt
-            re, im = _fused_pair(
-                re, im, a=off + step[1],
-                s=off + planlib.owner_pos(lay, mesh_axis),
-                mesh_axis=mesh_axis, inverse=inverse, plan=plan,
-                strategy=strategy, wire=wire)
-            lay = planlib.swap(lay, mesh_axis, nxt[2])
-            i += 2
-            continue
-        if step[0] == 'fft':
-            re, im = _fft_along(re, im, off + step[1], inverse=inverse, plan=plan)
-        else:
-            _, mesh_axis, mem_pos = step
-            sp = planlib.owner_pos(lay, mesh_axis)
-            re = comm.strategies.swap_axes_wire(
-                strategy, re, mesh_axis, shard_pos=off + sp,
-                mem_pos=off + mem_pos, wire_dtype=wire)
-            im = comm.strategies.swap_axes_wire(
-                strategy, im, mesh_axis, shard_pos=off + sp,
-                mem_pos=off + mem_pos, wire_dtype=wire)
-            lay = planlib.swap(lay, mesh_axis, mem_pos)
-        i += 1
+            if step[0] == 'fft':
+                re, im = _fft_along(re, im, off + step[1], inverse=inverse,
+                                    plan=plan)
+            else:
+                _, mesh_axis, mem_pos = step
+                sp = planlib.owner_pos(lay, mesh_axis)
+                re = comm.strategies.swap_axes_wire(
+                    strategy, re, mesh_axis, shard_pos=off + sp,
+                    mem_pos=off + mem_pos, wire_dtype=wire)
+                im = comm.strategies.swap_axes_wire(
+                    strategy, im, mesh_axis, shard_pos=off + sp,
+                    mem_pos=off + mem_pos, wire_dtype=wire)
+                lay = planlib.swap(lay, mesh_axis, mem_pos)
+            i += 1
     return re, im
 
 
@@ -372,26 +397,31 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
         off = batch_ndim
         strategy = comm.resolve(plan.comm)
 
+        ids = superstep_ids(steps)
+
         def r2c(x):
             re, im = methods.apply_real(x, axis=off + ra,
                                         method=plan.method,
                                         compute_dtype=plan.compute_dtype)
-            if nh_pad != nh:
-                pw = [(0, 0)] * re.ndim
-                pw[off + ra] = (0, nh_pad - nh)
-                re, im = jnp.pad(re, pw), jnp.pad(im, pw)
-            # pin the fusion boundary between the Hermitian combine and
-            # the following collective: without it XLA contracts the
-            # combine's mul/add chains differently per batch shape, and
-            # batched (serving) executions stop being bit-identical to
-            # per-request ones (measured at 32^3; the complex pipeline
-            # has no such epilogue and is stable without help)
-            return comm.strategies.dbarrier((re, im))
+            with scopes.scope(scopes.RELAYOUT):
+                if nh_pad != nh:
+                    pw = [(0, 0)] * re.ndim
+                    pw[off + ra] = (0, nh_pad - nh)
+                    re, im = jnp.pad(re, pw), jnp.pad(im, pw)
+                # pin the fusion boundary between the Hermitian combine
+                # and the following collective: without it XLA contracts
+                # the combine's mul/add chains differently per batch
+                # shape, and batched (serving) executions stop being
+                # bit-identical to per-request ones (measured at 32^3;
+                # the complex pipeline has no such epilogue and is
+                # stable without help)
+                return comm.strategies.dbarrier((re, im))
 
         def c2r(re, im):
-            re, im = comm.strategies.dbarrier((re, im))
-            re = jax.lax.slice_in_dim(re, 0, nh, axis=off + ra)
-            im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
+            with scopes.scope(scopes.RELAYOUT):
+                re, im = comm.strategies.dbarrier((re, im))
+                re = jax.lax.slice_in_dim(re, 0, nh, axis=off + ra)
+                im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
             return methods.apply_real(re, im, axis=off + ra, inverse=True,
                                       method=plan.method,
                                       compute_dtype=plan.compute_dtype)
@@ -422,16 +452,19 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
                                     strategy, ci, mesh_axis,
                                     shard_pos=off + sp, mem_pos=off + mem_pos,
                                     wire_dtype=plan.wire_dtype))
-                    re, im = ov.pipelined(overlap_chunks, ck, stage, x)
+                    with scopes.scope(scopes.step(ids[0])):
+                        re, im = ov.pipelined(overlap_chunks, ck, stage, x)
                     lay = planlib.swap(in_layout, mesh_axis, mem_pos)
                     return _execute(re, im, lay, rest[1:], inverse=False,
                                     plan=packed, batch_ndim=batch_ndim,
                                     overlap_chunks=overlap_chunks,
-                                    fused=fused)
-            re, im = r2c(x)
+                                    fused=fused, ids=ids[2:])
+            with scopes.scope(scopes.step(ids[0])):
+                re, im = r2c(x)
             return _execute(re, im, in_layout, rest, inverse=False,
                             plan=packed, batch_ndim=batch_ndim,
-                            overlap_chunks=overlap_chunks, fused=fused)
+                            overlap_chunks=overlap_chunks, fused=fused,
+                            ids=ids[1:])
 
         def local_real_inv(re, im):
             assert steps[-1] == ('fft', ra), steps
@@ -457,8 +490,12 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
                     head = head[:-1]
             re, im = _execute(re, im, in_layout, head, inverse=True,
                               plan=packed, batch_ndim=batch_ndim,
-                              overlap_chunks=overlap_chunks, fused=fused)
-            if tail is not None:
+                              overlap_chunks=overlap_chunks, fused=fused,
+                              ids=ids[:len(head)])
+            # the pipelined (swap, c2r) tail is one superstep: the c2r's
+            with scopes.scope(scopes.step(ids[-1])):
+                if tail is None:
+                    return c2r(re, im)
                 mesh_axis, mem_pos, sp, ck = tail
 
                 def stage_inv(cr, ci):
@@ -470,7 +507,6 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
                         mem_pos=off + mem_pos, wire_dtype=plan.wire_dtype)
                     return c2r(cr, ci)
                 return ov.pipelined(overlap_chunks, ck, stage_inv, re, im)
-            return c2r(re, im)
 
         if inverse:
             fn = jax.shard_map(local_real_inv, mesh=plan.mesh,
@@ -486,37 +522,41 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
         if plan.method == 'block':
             # §Perf iteration 2: block-complex state (leading axis 2) —
             # each superstep is two dots, the transposes move one array
-            x = jnp.stack([re, im])
+            with scopes.scope(scopes.BOUNDARY):
+                x = jnp.stack([re, im])
             off = batch_ndim + 1
             lay = in_layout
             strategy = comm.resolve(plan.comm)
-            for step in steps:
+            for step, k in zip(steps, superstep_ids(steps)):
                 if step[0] == 'fft':
-                    x = methods.apply_block(
-                        x, axis=off + step[1], inverse=inverse,
-                        compute_dtype=plan.compute_dtype,
-                        kernel=plan.kernel_tier)
-                else:
-                    _, mesh_axis, mem_pos = step
-                    sp = planlib.owner_pos(lay, mesh_axis)
-                    narrow = x.dtype == jnp.bfloat16
-                    if narrow:
+                    with scopes.scope(scopes.step(k)):
+                        x = methods.apply_block(
+                            x, axis=off + step[1], inverse=inverse,
+                            compute_dtype=plan.compute_dtype,
+                            kernel=plan.kernel_tier)
+                    continue
+                _, mesh_axis, mem_pos = step
+                sp = planlib.owner_pos(lay, mesh_axis)
+                with scopes.scope(scopes.step(k)):
+                    if x.dtype == jnp.bfloat16:
                         # pin the narrow dtype ON the wire: without the
                         # barriers XLA hoists the consumer's f32 upcast
                         # across the all_to_all, doubling transpose
                         # bytes (measured; CPU-backend dots upcast bf16)
-                        x = comm.strategies.dbarrier(x)
-                        x = strategy.swap_axes(x, mesh_axis,
-                                               shard_pos=off + sp,
-                                               mem_pos=off + mem_pos)
-                        x = comm.strategies.dbarrier(x)
+                        with scopes.scope(scopes.SWAP):
+                            x = comm.strategies.dbarrier(x)
+                            x = strategy.swap_axes(x, mesh_axis,
+                                                   shard_pos=off + sp,
+                                                   mem_pos=off + mem_pos)
+                            x = comm.strategies.dbarrier(x)
                     else:
                         x = comm.strategies.swap_axes_wire(
                             strategy, x, mesh_axis, shard_pos=off + sp,
                             mem_pos=off + mem_pos,
                             wire_dtype=plan.wire_dtype)
-                    lay = planlib.swap(lay, mesh_axis, mem_pos)
-            return x[0], x[1]
+                lay = planlib.swap(lay, mesh_axis, mem_pos)
+            with scopes.scope(scopes.BOUNDARY):
+                return x[0], x[1]
         return _execute(re, im, in_layout, steps, inverse=inverse, plan=plan,
                         batch_ndim=batch_ndim, overlap_chunks=overlap_chunks,
                         fused=fused)
@@ -582,23 +622,29 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
         packed = packed_plan(plan, nh_pad)
         assert fsteps[0] == ('fft', ra) and isteps[-1] == ('fft', ra)
 
+        fids, iids = superstep_ids(fsteps), superstep_ids(isteps)
+
         def r2c(x, off):
-            re, im = methods.apply_real(x, axis=off + ra,
-                                        method=plan.method,
-                                        compute_dtype=plan.compute_dtype)
-            if nh_pad != nh:
-                pw = [(0, 0)] * re.ndim
-                pw[off + ra] = (0, nh_pad - nh)
-                re, im = jnp.pad(re, pw), jnp.pad(im, pw)
-            return barrier((re, im))
+            with scopes.scope(scopes.step(fids[0])):
+                re, im = methods.apply_real(x, axis=off + ra,
+                                            method=plan.method,
+                                            compute_dtype=plan.compute_dtype)
+                with scopes.scope(scopes.RELAYOUT):
+                    if nh_pad != nh:
+                        pw = [(0, 0)] * re.ndim
+                        pw[off + ra] = (0, nh_pad - nh)
+                        re, im = jnp.pad(re, pw), jnp.pad(im, pw)
+                    return barrier((re, im))
 
         def c2r(re, im, off):
-            re, im = barrier((re, im))
-            re = jax.lax.slice_in_dim(re, 0, nh, axis=off + ra)
-            im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
-            return methods.apply_real(re, im, axis=off + ra, inverse=True,
-                                      method=plan.method,
-                                      compute_dtype=plan.compute_dtype)
+            with scopes.scope(scopes.step(iids[-1])):
+                with scopes.scope(scopes.RELAYOUT):
+                    re, im = barrier((re, im))
+                    re = jax.lax.slice_in_dim(re, 0, nh, axis=off + ra)
+                    im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
+                return methods.apply_real(re, im, axis=off + ra,
+                                          inverse=True, method=plan.method,
+                                          compute_dtype=plan.compute_dtype)
 
         def local(*args):
             mains, baked = args[:1 + n_extra], args[1 + n_extra:]
@@ -616,7 +662,8 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
                 re, im = r2c(x, nb)
                 re, im = _execute(re, im, in_layout, fsteps[1:],
                                   inverse=False, plan=packed, batch_ndim=nb,
-                                  overlap_chunks=overlap_chunks, fused=fused)
+                                  overlap_chunks=overlap_chunks, fused=fused,
+                                  ids=fids[1:])
                 # pin the splice point: the forward section must compile
                 # exactly like the standalone plan so fused == unfused
                 # stays bitwise (same rationale as the r2c barrier)
@@ -629,7 +676,8 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
             nb = batch_ndims[0]
             re, im = _execute(re, im, spec_layout, isteps[:-1], inverse=True,
                               plan=packed, batch_ndim=nb,
-                              overlap_chunks=overlap_chunks, fused=fused)
+                              overlap_chunks=overlap_chunks, fused=fused,
+                              ids=iids[:-1])
             return c2r(re, im, nb)
 
         in_specs = (tuple(bspec(nb, in_layout) for nb in batch_ndims)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.kernels.fft_matmul import (DEFAULT_BLOCK_B, default_factors,
                                       pallas_fft)
@@ -32,8 +33,10 @@ def fft_block(x: jnp.ndarray, *, inverse: bool = False,
         raise ValueError(f"pencil length must be pow2, got {n}")
     batch_shape = x.shape[1:-1]
     b = int(np.prod(batch_shape)) if batch_shape else 1
-    xr = x.reshape(2, b, n)
+    with scopes.scope(scopes.RELAYOUT):
+        xr = x.reshape(2, b, n)
     yr, yi = pallas_fft(xr[0], xr[1], inverse=inverse,
                         factors=default_factors(n), block_b=block_b,
                         interpret=interpret, block_complex=True)
-    return jnp.stack([yr, yi]).reshape((2,) + batch_shape + (n,))
+    with scopes.scope(scopes.RELAYOUT):
+        return jnp.stack([yr, yi]).reshape((2,) + batch_shape + (n,))

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.fft.methods import default_interpret
 from repro.kernels.fft_pencil import (DEFAULT_BLOCK_B, _stockham_rows,
@@ -81,22 +82,22 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
     lead = re.shape[:-2]
     nl = int(np.prod(lead)) if lead else 1
     has_w = wr is not None
-    xr = re.reshape(nl, b, n)
-    xi = im.reshape(nl, b, n)
-    if has_w:
-        twr = jnp.broadcast_to(jnp.asarray(wr, re.dtype),
-                               re.shape).reshape(nl, b, n)
-        twi = jnp.broadcast_to(jnp.asarray(wi, re.dtype),
-                               re.shape).reshape(nl, b, n)
-
     bb = block_rows(b, block_b)
     pad = (-b) % bb
-    if pad:
-        xr = jnp.pad(xr, ((0, 0), (0, pad), (0, 0)))
-        xi = jnp.pad(xi, ((0, 0), (0, pad), (0, 0)))
+    with scopes.scope(scopes.RELAYOUT):
+        xr = re.reshape(nl, b, n)
+        xi = im.reshape(nl, b, n)
         if has_w:
-            twr = jnp.pad(twr, ((0, 0), (0, pad), (0, 0)))
-            twi = jnp.pad(twi, ((0, 0), (0, pad), (0, 0)))
+            twr = jnp.broadcast_to(jnp.asarray(wr, re.dtype),
+                                   re.shape).reshape(nl, b, n)
+            twi = jnp.broadcast_to(jnp.asarray(wi, re.dtype),
+                                   re.shape).reshape(nl, b, n)
+        if pad:
+            xr = jnp.pad(xr, ((0, 0), (0, pad), (0, 0)))
+            xi = jnp.pad(xi, ((0, 0), (0, pad), (0, 0)))
+            if has_w:
+                twr = jnp.pad(twr, ((0, 0), (0, pad), (0, 0)))
+                twi = jnp.pad(twi, ((0, 0), (0, pad), (0, 0)))
     bp = b + pad
 
     interpret = default_interpret() if interpret is None else interpret
@@ -119,6 +120,7 @@ def fft_twiddle_transpose(re: jnp.ndarray, im: jnp.ndarray,
         out_shape=out_shape,
         interpret=interpret,
     )(*ops)
-    if pad:
-        yr, yi = yr[:, :, :b], yi[:, :, :b]
-    return yr.reshape(lead + (n, b)), yi.reshape(lead + (n, b))
+    with scopes.scope(scopes.RELAYOUT):
+        if pad:
+            yr, yi = yr[:, :, :b], yi[:, :, :b]
+        return yr.reshape(lead + (n, b)), yi.reshape(lead + (n, b))

@@ -33,6 +33,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.fft.methods import default_interpret
 from repro.kernels.fft_pencil import DEFAULT_BLOCK_B, block_rows
@@ -153,8 +154,9 @@ def pallas_fft(xr, xi, *, inverse: bool, factors: Tuple[int, int],
     bb = block_rows(b, block_b)
     pad = (-b) % bb
     if pad:
-        xr = jnp.pad(xr, ((0, pad), (0, 0)))
-        xi = jnp.pad(xi, ((0, pad), (0, 0)))
+        with scopes.scope(scopes.RELAYOUT):
+            xr = jnp.pad(xr, ((0, pad), (0, 0)))
+            xi = jnp.pad(xi, ((0, pad), (0, 0)))
     bp = b + pad
     dt = xr.dtype
     f, wr, wi = constants(n1, n2, inverse, block_complex and n2 > 1, dt)
@@ -181,7 +183,8 @@ def pallas_fft(xr, xi, *, inverse: bool, factors: Tuple[int, int],
         interpret=default_interpret() if interpret is None else interpret,
     )(*consts, xr, xi)
     if pad:
-        yr, yi = yr[:b], yi[:b]
+        with scopes.scope(scopes.RELAYOUT):
+            yr, yi = yr[:b], yi[:b]
     return yr, yi
 
 
@@ -200,7 +203,10 @@ def fft_matmul(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
         raise ValueError(f"pencil length must be pow2, got {n}")
     batch_shape = re.shape[:-1]
     b = int(np.prod(batch_shape)) if batch_shape else 1
-    yr, yi = pallas_fft(re.reshape(b, n), im.reshape(b, n), inverse=inverse,
+    with scopes.scope(scopes.RELAYOUT):
+        xr, xi = re.reshape(b, n), im.reshape(b, n)
+    yr, yi = pallas_fft(xr, xi, inverse=inverse,
                         factors=factors or default_factors(n),
                         block_b=block_b, interpret=interpret)
-    return yr.reshape(batch_shape + (n,)), yi.reshape(batch_shape + (n,))
+    with scopes.scope(scopes.RELAYOUT):
+        return yr.reshape(batch_shape + (n,)), yi.reshape(batch_shape + (n,))

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core import scopes
 from repro.core import twiddle as tw
 from repro.fft.methods import default_interpret
 
@@ -124,14 +125,14 @@ def fft_pencil(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
         raise ValueError(f"pencil length must be pow2, got {n}")
     batch_shape = re.shape[:-1]
     b = int(np.prod(batch_shape)) if batch_shape else 1
-    xr = re.reshape(b, n)
-    xi = im.reshape(b, n)
-
     bb = block_rows(b, block_b)
     pad = (-b) % bb
-    if pad:
-        xr = jnp.pad(xr, ((0, pad), (0, 0)))
-        xi = jnp.pad(xi, ((0, pad), (0, 0)))
+    with scopes.scope(scopes.RELAYOUT):
+        xr = re.reshape(b, n)
+        xi = im.reshape(b, n)
+        if pad:
+            xr = jnp.pad(xr, ((0, pad), (0, 0)))
+            xi = jnp.pad(xi, ((0, pad), (0, 0)))
     bp = b + pad
 
     interpret = default_interpret() if interpret is None else interpret
@@ -146,6 +147,7 @@ def fft_pencil(re: jnp.ndarray, im: jnp.ndarray, *, inverse: bool = False,
                    jax.ShapeDtypeStruct((bp, n), im.dtype)],
         interpret=interpret,
     )(wr, wi, xr, xi)
-    if pad:
-        yr, yi = yr[:b], yi[:b]
-    return yr.reshape(batch_shape + (n,)), yi.reshape(batch_shape + (n,))
+    with scopes.scope(scopes.RELAYOUT):
+        if pad:
+            yr, yi = yr[:b], yi[:b]
+        return yr.reshape(batch_shape + (n,)), yi.reshape(batch_shape + (n,))
