@@ -5,10 +5,12 @@ against float64 ``numpy.fft``.
     python chip_smoke.py          # one chip
     python chip_smoke.py --four   # 2x2 mesh on a four-chip host
 
-One chip runs four phases: c2c 512^3 forward and inverse, r2c 512^3
+One chip runs five phases: c2c 512^3 forward and inverse, r2c 512^3
 forward and inverse, a 512^3 operator plan with a baked spectrum (a
-Poisson solve), and ``FFTService`` serving two tenants over a unix
-socket with mixed c2c/r2c requests at 32^3-128^3. ``--four`` runs only
+Poisson solve), ``FFTService`` serving two tenants over a unix socket
+with mixed c2c/r2c requests at 32^3-128^3, and r2c requests at
+32^3-128^3 coalesced by ``FFTEngine``, checked bitwise against
+per-request plan calls. ``--four`` runs only
 the c2c and r2c 512^3 phases on a 2x2 mesh and checks that every output
 is sharded over the four chips.
 
@@ -249,6 +251,36 @@ def phase_service(mesh, rng) -> None:
     log(f'  peak_bytes_in_use {peak_bytes()}')
 
 
+def phase_batched(mesh, rng) -> None:
+    """Served r2c requests coalesced by ``FFTEngine`` (width 4, two
+    overlap chunks) give the bits of per-request plan calls, forward and
+    inverse, at the served sizes, with every axis of the real plan on
+    the kernels."""
+    import repro.fft as fft
+    from repro.serve import FFTEngine
+    for n in (32, 64, 128):
+        shape = (n,) * 3
+        p = fft.rplan(shape, mesh)
+        describe(f'batched r2c {shape}', p)
+        eng = FFTEngine(shape, mesh, schedule_table=None)
+        eng.set_schedule(4, 2, real=True)
+        xs = [rng.standard_normal(shape, np.float32) for _ in range(5)]
+        ys = eng.transform(xs)
+        backs = eng.transform(ys, direction='inv')
+        for i, (x, y, b) in enumerate(zip(xs, ys, backs)):
+            for name, got, want in (('forward', y, p.forward(x)),
+                                    ('inverse', b, p.inverse(y))):
+                if not np.array_equal(np.asarray(got), np.asarray(want)):
+                    raise AssertionError(
+                        f'{shape} request {i} {name}: batched != '
+                        f'per-request, max abs diff '
+                        f'{np.max(np.abs(np.asarray(got) - want)):.3e}')
+        check('request 0 forward vs numpy.fft.rfftn', ys[0],
+              np.fft.rfftn(xs[0].astype(np.float64)))
+        log(f'  {len(xs)} requests: batched == per-request bitwise, '
+            f'forward and inverse')
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     ap.add_argument('--four', action='store_true',
@@ -282,6 +314,7 @@ def main(argv=None) -> int:
         phase_r2c(mesh, rng, 'real input r2c 512^3', 1)
         phase_op(mesh, rng)
         phase_service(mesh, rng)
+        phase_batched(mesh, rng)
     print(json.dumps({'ok': True, 'device': {
         'platform': dev.platform, 'kind': dev.device_kind,
         'count': len(devices)}}), flush=True)

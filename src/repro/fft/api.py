@@ -373,9 +373,17 @@ class FFT:
         backend ('pallas' | 'reference') — the 'auto' option resolved
         at query time against :data:`methods.PALLAS_LOWERING` and the
         method's per-backend kernel table. Each pencil length resolves
-        on its own, as :func:`methods.apply` does per axis; the plan is
-        'pallas' only when every one of them is."""
-        lengths = set(self._factors if self.rank == 1 else self.shape)
+        on its own, as :func:`methods.apply` does per axis, and a real
+        plan's real axis at its half length, as
+        :func:`methods.apply_real` does; the plan is 'pallas' only when
+        every one of them is."""
+        lengths = list(self._factors if self.rank == 1 else self.shape)
+        if self.real:
+            # the real axis: the rank-1 four-step's column factor n1,
+            # the last axis otherwise
+            ra = 0 if self.rank == 1 else -1
+            lengths[ra] //= 2
+        lengths = set(lengths)
         tiers = {methods.resolve_kernel(self.kernel,
                                         methods.resolve(self.method, n), n=n)
                  for n in lengths}

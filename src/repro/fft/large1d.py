@@ -239,7 +239,8 @@ def _real_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
             x = wswap(x, off + 0, off + 1)
             # r2c column DFT over k1 -> (nh1, n2/p), padded rows
             ar, ai = methods.apply_real(x, axis=off + 0, method=method,
-                                        compute_dtype=compute_dtype)
+                                        compute_dtype=compute_dtype,
+                                        kernel=kern)
             if nh1p != nh1:
                 pw = [(0, 0)] * ar.ndim
                 pw[off + 0] = (0, nh1p - nh1)
@@ -302,7 +303,8 @@ def _real_fourstep(n1, n2, psize, mesh_axis, strategy, wire_dtype,
                 ar = lax.slice_in_dim(ar, 0, nh1, axis=off + 0)
                 ai = lax.slice_in_dim(ai, 0, nh1, axis=off + 0)
             x = methods.apply_real(ar, ai, axis=off + 0, inverse=True,
-                                   method=method, compute_dtype=compute_dtype)
+                                   method=method, compute_dtype=compute_dtype,
+                                   kernel=kern)
             # swap the real array back to rows-sharded
             return wswap(x, off + 1, off + 0)
 

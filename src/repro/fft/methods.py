@@ -273,14 +273,17 @@ def _from_last(axis: int, *arrays):
 
 def apply_real(x: jnp.ndarray, im: Optional[jnp.ndarray] = None, *,
                axis: int = -1, inverse: bool = False, method: str = 'auto',
-               compute_dtype=None) -> object:
+               compute_dtype=None, kernel: str = 'auto') -> object:
     """Run a method's real-input transform along ``axis``.
 
     Forward (``im is None``): real array -> planar half spectrum, the
     ``axis`` extent going n -> n//2 + 1 (``np.fft.rfft`` layout).
     Inverse: planar half spectrum ``(x, im)`` -> real array, n//2 + 1
-    -> n. The ``'auto'`` rule resolves by the length of the underlying
-    *complex* sub-pencil (n//2) — that is where the flops go.
+    -> n. The ``'auto'`` method and kernel-tier rules resolve by the
+    length of the underlying *complex* sub-pencil (n//2) — that is
+    where the flops go. On the Pallas tier that sub-pencil runs on the
+    method's kernel, inside the same Hermitian combines as the
+    reference tier's ``real_fn``.
     """
     axis = axis % x.ndim
     if inverse:
@@ -294,26 +297,40 @@ def apply_real(x: jnp.ndarray, im: Optional[jnp.ndarray] = None, *,
         n = x.shape[axis]
     if n % 2:
         raise ValueError(f"real transforms need an even length, got {n}")
-    m = resolve(method, max(n // 2, 1))
-    if m.pow2_only and not tw.is_pow2(max(n // 2, 1)):
+    h = max(n // 2, 1)
+    m = resolve(method, h)
+    if m.pow2_only and not tw.is_pow2(h):
         raise ValueError(
             f"method {m.name!r} requires a power-of-two half length, "
             f"got n={n} (use method='direct' or 'auto')")
     if m.real_fn is None:
         raise ValueError(f"method {m.name!r} has no real-input form")
+    real_fn = m.real_fn
+    if resolve_kernel(kernel, m, n=h) == 'pallas':
+        real_fn = _kernel_real_fn(m.kernel_for())
     last = axis == x.ndim - 1
     with scopes.scope(scopes.PASS):
         if inverse:
             if not last:
                 x, im = _to_last(axis, x, im)
-            y = m.real_fn(x, im, inverse=True, compute_dtype=compute_dtype)
+            y = real_fn(x, im, inverse=True, compute_dtype=compute_dtype)
             return y if last else _from_last(axis, y)[0]
         if not last:
             (x,) = _to_last(axis, x)
-        yr, yi = m.real_fn(x, compute_dtype=compute_dtype)
+        yr, yi = real_fn(x, compute_dtype=compute_dtype)
         if not last:
             yr, yi = _from_last(axis, yr, yi)
         return yr, yi
+
+
+def _kernel_real_fn(kfn: Callable) -> Callable:
+    """``real_fn`` of the Pallas tier: the pack-two-reals halving trick
+    around kernel ``kfn`` as the half-length complex FFT (its inverse
+    folds in the 1/(n/2) that :func:`repro.core.fft1d.irfft_pencil`
+    expects; interpret mode per :func:`default_interpret`)."""
+    def pencil(re, im, *, inverse, compute_dtype=None):
+        return kfn(re, im, inverse=inverse, interpret=None)
+    return _f1.rfft_via(pencil)
 
 
 def apply_block(x: jnp.ndarray, *, axis: int, inverse: bool = False,

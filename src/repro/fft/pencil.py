@@ -402,7 +402,8 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
         def r2c(x):
             re, im = methods.apply_real(x, axis=off + ra,
                                         method=plan.method,
-                                        compute_dtype=plan.compute_dtype)
+                                        compute_dtype=plan.compute_dtype,
+                                        kernel=plan.kernel_tier)
             with scopes.scope(scopes.RELAYOUT):
                 if nh_pad != nh:
                     pw = [(0, 0)] * re.ndim
@@ -424,7 +425,8 @@ def make_fft(plan: PencilPlan, *, inverse: bool = False,
                 im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
             return methods.apply_real(re, im, axis=off + ra, inverse=True,
                                       method=plan.method,
-                                      compute_dtype=plan.compute_dtype)
+                                      compute_dtype=plan.compute_dtype,
+                                      kernel=plan.kernel_tier)
 
         def local_real_fwd(x):
             assert steps[0] == ('fft', ra), steps
@@ -628,7 +630,8 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
             with scopes.scope(scopes.step(fids[0])):
                 re, im = methods.apply_real(x, axis=off + ra,
                                             method=plan.method,
-                                            compute_dtype=plan.compute_dtype)
+                                            compute_dtype=plan.compute_dtype,
+                                            kernel=plan.kernel_tier)
                 with scopes.scope(scopes.RELAYOUT):
                     if nh_pad != nh:
                         pw = [(0, 0)] * re.ndim
@@ -644,7 +647,8 @@ def make_fused_op(plan: PencilPlan, pointwise, *,
                     im = jax.lax.slice_in_dim(im, 0, nh, axis=off + ra)
                 return methods.apply_real(re, im, axis=off + ra,
                                           inverse=True, method=plan.method,
-                                          compute_dtype=plan.compute_dtype)
+                                          compute_dtype=plan.compute_dtype,
+                                          kernel=plan.kernel_tier)
 
         def local(*args):
             mains, baked = args[:1 + n_extra], args[1 + n_extra:]

@@ -297,9 +297,12 @@ def fftconv_apply(p: Dict, cfg, x, *, mesh=None):
     if op is not None:
         yr = op.apply(hr, kr) if op.n_spectra else op.apply(hr)
     else:
-        hre, him = fftm.apply_real(hr, method='four_step')
-        kre, kim = fftm.apply_real(kr, method='four_step')
+        # the reference tier: training differentiates this path, and the
+        # Pallas kernels have no reverse-mode rule
+        hre, him = fftm.apply_real(hr, method='four_step', kernel='reference')
+        kre, kim = fftm.apply_real(kr, method='four_step', kernel='reference')
         yre, yim = fft.spectral_mul(hre, him, (kre, kim))
-        yr = fftm.apply_real(yre, yim, inverse=True, method='four_step')
+        yr = fftm.apply_real(yre, yim, inverse=True, method='four_step',
+                             kernel='reference')
     y = yr[..., :S].swapaxes(1, 2).astype(x.dtype)
     return L.apply_linear(p['wo'], y)

@@ -45,8 +45,12 @@ drainer is re-queued (never silently dropped) and retried up to
 
 Results are bit-identical to per-request ``plan.forward``/``inverse``
 execution — coalescing changes the schedule on the wire, never the
-values. Donation follows the plan contract: with ``donate=True`` every
-request's input buffer aliases its own output inside the group
+values. On XLA:CPU it holds for the Stockham method, save a real
+plan's inverse on the Pallas tier, whose c2r combine XLA:CPU contracts
+into FMAs differently per batch shape; that inverse and the matmul
+methods agree to rounding there. Donation follows the plan contract:
+with ``donate=True`` every request's input buffer aliases its own
+output inside the group
 executable (complex kinds), so submitted jax arrays are CONSUMED and
 each in-flight request holds one operand-sized buffer instead of two;
 numpy submissions are copied to device and the caller's data is

@@ -1,7 +1,8 @@
 """Worker script: kernel-tier equivalence on 16 fake host devices.
 
 Run in a *subprocess* (so the main pytest process keeps 1 device):
-    python tests/_kernel_tier_worker.py
+    python tests/_kernel_tier_worker.py            # the whole matrix
+    python tests/_kernel_tier_worker.py --no-fma   # real plans, no FMA
 Exits 0 on success; prints PASS lines per case.
 
 The contract under test: with everything jitted (plans always are),
@@ -9,13 +10,23 @@ The contract under test: with everything jitted (plans always are),
 ``kernel='reference'`` produce BIT-IDENTICAL outputs for the Stockham
 method across every comm strategy — the interpret-mode kernel runs the
 same float ops in the same order as the jnp reference, and XLA's jit
-rounding is deterministic. Likewise the fused twiddle+transpose
+rounding is deterministic. The one exception is a real plan's inverse on
+a sharded mesh, which agrees to rounding: XLA:CPU fuses its c2r
+Hermitian combine into the jnp Stockham's first stage on one tier and
+into a fusion of its own on the other, and contracts the combine's
+products into FMAs differently in the two. ``--no-fma`` caps XLA:CPU at
+an instruction set without FMA (``--xla_cpu_max_isa=SSE4_2``), and
+there the real plans are bitwise on every mesh, inverse included.
+Likewise the fused twiddle+transpose
 supersteps (the default) are a pure positional rearrangement around
 identical float ops, so ``fused=False`` re-plans match bit for bit.
 """
 import os
+import sys
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+NO_FMA = "--no-fma" in sys.argv[1:]
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=16"
+                           + (" --xla_cpu_max_isa=SSE4_2" if NO_FMA else ""))
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
@@ -36,6 +47,64 @@ def check_bitwise(name, a, b):
     assert np.array_equal(a, b), (
         f"{name}: max abs diff {np.max(np.abs(a - b)):.3e} (not bitwise)")
     print(f"PASS {name} bitwise")
+
+
+def check_real(mesh, tag, rng, *, bitwise_inverse, n=16):
+    """A real plan on both tiers: the tier applies to every axis, the
+    real axis's half-length FFT (between the Hermitian combines)
+    included. The forward is bitwise. The inverse, from one spectrum,
+    is bitwise where ``bitwise_inverse``, else equal to rounding."""
+    xr = rng.standard_normal((n, n, n)).astype(np.float32)
+    rplans = {tier: fft.rplan((n, n, n), mesh, method="stockham",
+                              kernel=tier)
+              for tier in ("reference", "pallas")}
+    yr = {t: p.forward(jnp.asarray(xr)) for t, p in rplans.items()}
+    check_bitwise(f"rfft3d {tag} pallas==reference",
+                  yr["pallas"], yr["reference"])
+    err = np.max(np.abs(np.asarray(yr["pallas"]) - np.fft.rfftn(xr)))
+    assert err < 1e-3, f"rfft err {err:.2e}"
+    xb = {t: np.asarray(p.inverse(yr["reference"]))
+          for t, p in rplans.items()}
+    err = np.max(np.abs(xb["pallas"] - xr))
+    assert err < 1e-5, f"irfft3d roundtrip err {err:.2e}"
+    if bitwise_inverse:
+        check_bitwise(f"irfft3d {tag} pallas==reference",
+                      xb["pallas"], xb["reference"])
+        return
+    diff = np.max(np.abs(xb["pallas"] - xb["reference"])) / np.max(np.abs(xr))
+    assert diff < 1e-6, f"irfft3d pallas vs reference rel {diff:.2e}"
+    print(f"PASS irfft3d {tag} pallas~reference rel_diff={diff:.2e}")
+
+
+def check_engine_real(mesh, tag, rng):
+    """Coalesced real requests through ``FFTEngine`` (width 4, two
+    overlap chunks), forward and inverse, give the bits of per-request
+    plan calls on both tiers."""
+    from repro.serve import FFTEngine
+    shape = (16, 16, 32)
+    for tier in ("reference", "pallas"):
+        eng = FFTEngine(shape, mesh, method="stockham", kernel=tier,
+                        schedule_table=None)
+        eng.set_schedule(4, 2, real=True)
+        p = fft.rplan(shape, mesh, method="stockham", kernel=tier)
+        xs = [rng.standard_normal(shape).astype(np.float32)
+              for _ in range(5)]
+        ys = eng.transform(xs)
+        backs = eng.transform(ys, direction="inv")
+        for i, (x, y, b) in enumerate(zip(xs, ys, backs)):
+            check_bitwise(f"engine rfft3d {tag} {tier} batched==request {i}",
+                          y, p.forward(x))
+            check_bitwise(f"engine irfft3d {tag} {tier} batched==request {i}",
+                          b, p.inverse(y))
+
+
+def main_no_fma():
+    rng = np.random.default_rng(11)
+    for shape in ((2, 2), (4, 4)):
+        check_real(make_mesh(shape, ("x", "y")), "x".join(map(str, shape)),
+                   rng, bitwise_inverse=True)
+    check_engine_real(make_mesh((2, 2), ("x", "y")), "2x2", rng)
+    print("KERNEL_TIER_WORKER_OK")
 
 
 def main():
@@ -114,19 +183,13 @@ def main():
     }
     check_bitwise("fft2d pallas==reference", y2["pallas"], y2["reference"])
 
-    # ---- real (rfft) plan: tier applies to the post-r2c supersteps ----
-    xr = rng.standard_normal((n, n, n)).astype(np.float32)
-    yr = {
-        tier: fft.rplan((n, n, n), mesh, method="stockham",
-                        kernel=tier).forward(jnp.asarray(xr))
-        for tier in ("reference", "pallas")
-    }
-    check_bitwise("rfft3d pallas==reference", yr["pallas"], yr["reference"])
-    err = np.max(np.abs(np.asarray(yr["pallas"]) - np.fft.rfftn(xr)))
-    assert err < 1e-3, f"rfft err {err:.2e}"
+    # ---- real (rfft) plans on a sharded mesh and on one device ----
+    check_real(mesh, "4x4", rng, bitwise_inverse=False)
+    check_real(make_mesh((1, 1), ("x", "y")), "1x1", rng,
+               bitwise_inverse=True)
 
     print("KERNEL_TIER_WORKER_OK")
 
 
 if __name__ == "__main__":
-    main()
+    main_no_fma() if NO_FMA else main()

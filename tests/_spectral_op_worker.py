@@ -168,20 +168,26 @@ def check_kernel_tiers(mesh):
 
 
 def check_batch_broadcast(mesh):
+    """A batch of mains against one unbatched spectrum: bitwise equal to
+    per-request applies, on the plan's own tier ('auto') and with every
+    axis of the real plan, the real axis included, on the kernels."""
     shape = SHAPES[2]
     xb = RNG.standard_normal((2,) + shape).astype(np.float32)
     k = RNG.standard_normal(shape).astype(np.float32)
-    op = fft.plan_op(shape, mesh, op=fft.spectral_mul, real=True,
-                     n_spectra=1)
-    got = np.asarray(op.apply(jnp.asarray(xb), jnp.asarray(k)))
-    per = np.stack([np.asarray(op.apply(jnp.asarray(xb[i]),
-                                        jnp.asarray(k)))
-                    for i in range(2)])
-    check_bitwise("batched main x unbatched kernel", got, per)
     want = np_conv(xb, k, 2)
-    err = np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30)
-    assert err < 3e-4, err
-    print(f"PASS batched conv matches numpy rel_err={err:.2e}")
+    for tier in ("auto", "pallas"):
+        op = fft.plan_op(shape, mesh, op=fft.spectral_mul, real=True,
+                         n_spectra=1, kernel=tier)
+        got = np.asarray(op.apply(jnp.asarray(xb), jnp.asarray(k)))
+        per = np.stack([np.asarray(op.apply(jnp.asarray(xb[i]),
+                                            jnp.asarray(k)))
+                        for i in range(2)])
+        check_bitwise(f"batched main x unbatched kernel, tier {tier}", got,
+                      per)
+        err = np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30)
+        assert err < 3e-4, err
+        print(f"PASS batched conv, tier {tier}, matches numpy "
+              f"rel_err={err:.2e}")
 
 
 def check_overlap(mesh):

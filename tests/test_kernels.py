@@ -274,3 +274,26 @@ def test_kernel_tier_16_devices():
         f"STDOUT:\n{r.stdout[-4000:]}\nSTDERR:\n{r.stderr[-4000:]}")
     assert "KERNEL_TIER_WORKER_OK" in r.stdout
     assert r.stdout.count("PASS") >= 18
+
+
+@pytest.mark.slow
+def test_kernel_tier_real_plans_bitwise_without_fma():
+    """With XLA:CPU held to an instruction set without FMA, a sharded
+    real plan's inverse is bitwise on both tiers, and so are coalesced
+    real requests on the Pallas tier against per-request calls: FMA
+    contraction is the whole of the rounding difference that the main
+    matrix and the in-process engine test allow there."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tests", "_kernel_tier_worker.py"),
+         "--no-fma"],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, (
+        f"STDOUT:\n{r.stdout[-4000:]}\nSTDERR:\n{r.stderr[-4000:]}")
+    assert "KERNEL_TIER_WORKER_OK" in r.stdout
+    # rfft3d and irfft3d on 2x2 and 4x4
+    assert r.stdout.count("pallas==reference bitwise") == 4
+    # 5 requests x 2 directions x 2 tiers
+    assert r.stdout.count("batched==request") == 20

@@ -33,19 +33,98 @@ def mesh():
 # Local r2c/c2r machinery
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("method", ["stockham", "four_step", "block",
-                                    "direct", "auto"])
-def test_apply_real_matches_numpy(method):
-    x = RNG.standard_normal((3, 64)).astype(np.float32)
-    yr, yi = methods.apply_real(jnp.asarray(x), method=method)
-    want = np.fft.rfft(x, axis=-1)
+#: the real axis's half-length FFT on either tier: the kernel in interpret
+#: mode on this CPU host, or the jnp reference
+TIERS = ("reference", "pallas")
+
+#: (method, n, tier): every method on the reference tier at n = 64, and
+#: the kernels of four_step (n/2 = 128..512) and stockham
+APPLY_REAL_CASES = (
+    [pytest.param(m, 64, "reference", id=m)
+     for m in ("stockham", "four_step", "block", "direct", "auto")]
+    + [pytest.param(m, n, "pallas", id=f"pallas-{m}-{n}")
+       for m, n in (("four_step", 256), ("four_step", 512),
+                    ("four_step", 1024), ("stockham", 64))])
+
+
+@pytest.mark.parametrize("method,n,tier", APPLY_REAL_CASES)
+def test_apply_real_matches_numpy(method, n, tier):
+    """Forward and inverse match numpy on a batch of pencils. On the
+    Pallas tier the half-length complex FFT runs on the method's kernel
+    (interpret mode here), inside the same Hermitian combines."""
+    x = RNG.standard_normal((3, 5, n)).astype(np.float32)
+    fwd = jax.jit(lambda a: methods.apply_real(a, method=method,
+                                               kernel=tier))
+    inv = jax.jit(lambda r, i: methods.apply_real(
+        r, i, inverse=True, method=method, kernel=tier))
+    yr, yi = fwd(jnp.asarray(x))
+    want = np.fft.rfft(x.astype(np.float64), axis=-1)
     got = np.asarray(yr, np.float64) + 1j * np.asarray(yi, np.float64)
-    np.testing.assert_allclose(got, want, atol=3e-4 * np.max(np.abs(want)))
+    np.testing.assert_allclose(got, want, atol=2e-6 * np.max(np.abs(want)))
     # bins 0 and n/2 have exactly-zero imaginary parts by construction
-    assert np.all(np.asarray(yi)[:, 0] == 0)
-    assert np.all(np.asarray(yi)[:, -1] == 0)
-    back = methods.apply_real(yr, yi, inverse=True, method=method)
-    np.testing.assert_allclose(np.asarray(back), x, atol=1e-4)
+    assert np.all(np.asarray(yi)[..., [0, -1]] == 0)
+    np.testing.assert_allclose(np.asarray(inv(yr, yi)), x, atol=1e-4)
+    spec = want.astype(np.complex64)
+    back = inv(jnp.asarray(spec.real), jnp.asarray(spec.imag))
+    np.testing.assert_allclose(np.asarray(back, np.float64),
+                               np.fft.irfft(spec.astype(np.complex128),
+                                            n=n, axis=-1),
+                               atol=2e-6 * np.max(np.abs(x)))
+
+
+@pytest.mark.parametrize("inverse", [False, True])
+def test_apply_real_stockham_tiers_bitwise(inverse):
+    """The Stockham kernel runs the reference's float ops in the same
+    order, so the real axis is bit-identical on both tiers."""
+    x = RNG.standard_normal((4, 3, 32)).astype(np.float32)
+    if inverse:
+        yr, yi = methods.apply_real(jnp.asarray(x), method="stockham")
+        args = (yr, yi)
+    else:
+        args = (jnp.asarray(x),)
+    got = {t: jax.jit(lambda *a, t=t: methods.apply_real(
+        *a, inverse=inverse, method="stockham", kernel=t))(*args)
+        for t in TIERS}
+    for a, b in zip(jax.tree.leaves(got["pallas"]),
+                    jax.tree.leaves(got["reference"])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("inverse", [False, True])
+@pytest.mark.parametrize("method", ["stockham", "four_step", "block",
+                                    "direct"])
+def test_apply_real_auto_is_reference_on_cpu(method, inverse):
+    """``kernel='auto'`` resolves to the reference tier on the CPU, so
+    the real axis gives the bits of the method's own ``real_fn``."""
+    x = RNG.standard_normal((3, 128)).astype(np.float32)
+    m = methods.get(method)
+    if inverse:
+        yr, yi = methods.apply_real(jnp.asarray(x), method=method)
+        args = (yr, yi)
+        got = jax.jit(lambda r, i: methods.apply_real(
+            r, i, inverse=True, method=method))(*args)
+        want = jax.jit(lambda r, i: m.real_fn(r, i, inverse=True))(*args)
+    else:
+        got = jax.jit(lambda a: methods.apply_real(a, method=method))(x)
+        want = jax.jit(m.real_fn)(x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("inverse", [False, True])
+@pytest.mark.parametrize("tier", TIERS)
+@pytest.mark.parametrize("method", ["stockham", "four_step"])
+def test_apply_real_kernel_engagement(method, tier, inverse):
+    """One ``pallas_call`` in each direction of the real axis on the
+    Pallas tier, none on the reference tier."""
+    n = 512
+    if inverse:
+        args = (jnp.zeros((4, n // 2 + 1)),) * 2
+    else:
+        args = (jnp.zeros((4, n)),)
+    jaxpr = str(jax.make_jaxpr(lambda *a: methods.apply_real(
+        *a, inverse=inverse, method=method, kernel=tier))(*args))
+    assert jaxpr.count("pallas_call") == (tier == "pallas")
 
 
 def _reverse_readers(closed_jaxpr):
@@ -78,9 +157,10 @@ def _reverse_readers(closed_jaxpr):
     return readers
 
 
+@pytest.mark.parametrize("tier", TIERS)
 @pytest.mark.parametrize("n", [2, 4, 16, 64, 512])
 @pytest.mark.parametrize("method", ["stockham", "four_step"])
-def test_c2r_epilogue_has_no_reverse(n, method):
+def test_c2r_epilogue_has_no_reverse(n, method, tier):
     """The c2r epilogue of the sharded irfft: padded half spectrum ->
     slice to n//2 + 1 bins -> inverse real transform. No reverse of the
     pencil axis is fused into its Hermitian combine (XLA:TPU computed
@@ -96,7 +176,7 @@ def test_c2r_epilogue_has_no_reverse(n, method):
 
     def c2r(re, im):
         return methods.apply_real(re[..., :nh], im[..., :nh], axis=-1,
-                                  inverse=True, method=method)
+                                  inverse=True, method=method, kernel=tier)
 
     re, im = jnp.asarray(spec.real), jnp.asarray(spec.imag)
     readers = _reverse_readers(jax.make_jaxpr(c2r)(re, im))
@@ -106,16 +186,17 @@ def test_c2r_epilogue_has_no_reverse(n, method):
     np.testing.assert_allclose(got, want, atol=2e-6 * np.sqrt(n))
 
 
+@pytest.mark.parametrize("tier", TIERS)
 @pytest.mark.parametrize("n", [2, 4, 16, 64, 512])
 @pytest.mark.parametrize("method", ["stockham", "four_step"])
-def test_r2c_combine_has_no_reverse(n, method):
+def test_r2c_combine_has_no_reverse(n, method, tier):
     """The forward's Hermitian combine reads its mirrored bins by a
     gather, with no reverse of the pencil axis anywhere, and matches
     numpy.fft.rfft."""
     x = RNG.standard_normal((3, 2, n)).astype(np.float32)
 
     def r2c(a):
-        return methods.apply_real(a, axis=-1, method=method)
+        return methods.apply_real(a, axis=-1, method=method, kernel=tier)
 
     xd = jnp.asarray(x)
     assert "rev" not in str(jax.make_jaxpr(r2c)(xd))

@@ -114,6 +114,36 @@ def test_engine_from_existing_plan(mesh):
     np.testing.assert_allclose(got, want, atol=3e-4 * np.max(np.abs(want)))
 
 
+@pytest.mark.parametrize("kernel", ["reference", "pallas"])
+def test_engine_real_batched_matches_per_request(mesh, kernel):
+    """Coalesced real requests give the bits of per-request plan calls
+    on either tier, the Pallas tier running every axis on the kernel
+    (interpret mode here), the real axis's half-length FFT included. The
+    one exception is the Pallas tier's inverse, which agrees to rounding
+    on XLA:CPU: its c2r combine fuses with the batch's relayout and is
+    contracted into FMAs differently per batch shape. Without FMA it is
+    bitwise too (``tests/_kernel_tier_worker.py --no-fma``)."""
+    shape = (8, 8, 32)
+    eng = FFTEngine(shape, mesh, method='stockham', kernel=kernel,
+                    schedule_table=None)
+    eng.set_schedule(4, 2, real=True)
+    p = fft.rplan(shape, mesh, method='stockham', kernel=kernel)
+    assert eng.plan_for(True).resolved_kernel == p.resolved_kernel == kernel
+    xs = [RNG.standard_normal(shape).astype(np.float32) for _ in range(5)]
+    ys = eng.transform(xs)
+    for x, y in zip(xs, ys):
+        assert np.array_equal(np.asarray(y), np.asarray(p.forward(x)))
+    backs = eng.transform(ys, direction='inv')
+    for y, b in zip(ys, backs):
+        want = np.asarray(p.inverse(y))
+        if kernel == 'reference':
+            assert np.array_equal(np.asarray(b), want)
+        else:
+            np.testing.assert_allclose(np.asarray(b), want, rtol=0,
+                                       atol=1e-6 * np.max(np.abs(want)))
+    np.testing.assert_allclose(np.asarray(backs[0]), xs[0], atol=1e-5)
+
+
 def test_engine_schedule_knobs(mesh):
     eng = FFTEngine((8, 8, 8), mesh, max_coalesce=4, overlap_chunks=2)
     w, c = eng.schedule(False)

@@ -362,6 +362,19 @@ def test_fftconv_gradients_flow(mesh):
         assert np.all(np.isfinite(ga)) and np.max(np.abs(ga)) > 0, name
 
 
+def test_fftconv_local_path_differentiates_on_tpu(monkeypatch):
+    """Without a mesh the mixer's real transforms stay on the reference
+    tier where 'auto' would pick the Pallas kernels (the TPU): training
+    differentiates them, and the kernels have no reverse-mode rule."""
+    cfg, p, x = _fftconv_fixture()
+    monkeypatch.setattr(fftm, 'backend', lambda: 'tpu')
+
+    def loss(pp):
+        return jnp.sum(ssd.fftconv_apply(pp, cfg, x) ** 2)
+
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(loss))(p))
+
+
 # -- engine integration (1x1 mesh; the 16-device flow is in the worker) -
 
 

@@ -1,9 +1,9 @@
 """Ahead-of-time compiles for a described TPU v5e 2x2 (no chip needed):
 the four Pallas kernels at the 512-point pencils and block sizes of the
 512^3 plan, each lowered by Mosaic (``tpu_custom_call`` in the
-program), the c2r epilogue and the r2c combine at the per-device shapes
-of the sharded 512^3 real plan, and the 512^3 plans themselves on the
-2x2 mesh.
+program), the c2r epilogue and the r2c combine on either tier at the
+per-device shapes of the 512^3 real plan on one chip and on 2x2, and
+the 512^3 plans themselves on the 2x2 mesh.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library.
@@ -85,38 +85,64 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, inverse):
     assert "tpu_custom_call" in hlo, kernel
 
 
-def test_c2r_epilogue_compiles_for_v5e(one_chip):
-    """The last superstep of the sharded 512^3 irfft on one device: the
-    padded half spectrum (256, 256, 258) -> the real (256, 256, 512).
-    XLA:TPU computed a reverse of the pencil axis fused into this
-    combine wrongly; the program must not fuse one."""
+#: the real axis's tiers: the jnp reference, and the Mosaic kernel as the
+#: half-length complex FFT inside the Hermitian combines
+REAL_TIERS = ("reference", "pallas")
+
+#: the real axis's batch of pencils on one device: the sharded 512^3 real
+#: plan's block on the 2x2 mesh, and the whole 512^3 grid on one chip
+REAL_BATCHES = {"2x2": LOCAL, "1chip": (N, N)}
+
+
+@pytest.mark.parametrize("batch", sorted(REAL_BATCHES))
+@pytest.mark.parametrize("tier", sorted(REAL_TIERS))
+def test_c2r_epilogue_compiles_for_v5e(one_chip, monkeypatch, tier, batch):
+    """The last superstep of the 512^3 irfft on one device: the padded
+    half spectrum (..., 258) -> the real (..., 512). XLA:TPU computed a
+    reverse of the pencil axis fused into this combine wrongly; the
+    program must not fuse one, whichever tier runs the half-length FFT
+    between the combine and the interleave."""
     from repro.fft import methods
+    # the kernel's interpret mode follows the backend: steer it to the
+    # described chip's, so the kernel lowers with Mosaic
+    monkeypatch.setattr(methods, "backend", lambda: "tpu")
     nh = N // 2 + 1
+    lead = REAL_BATCHES[batch]
 
     def c2r(re, im):
         return methods.apply_real(re[..., :nh], im[..., :nh], axis=-1,
-                                  inverse=True, method="four_step")
+                                  inverse=True, method="four_step",
+                                  kernel=tier)
 
-    spec = jax.ShapeDtypeStruct(LOCAL + (nh + 1,), jnp.float32,
+    spec = jax.ShapeDtypeStruct(lead + (nh + 1,), jnp.float32,
                                 sharding=one_chip)
     out = jax.eval_shape(c2r, spec, spec)
-    assert out.shape == LOCAL + (N,)
-    assert not _reverse_fused_with_arithmetic(_compile(c2r, spec, spec))
+    assert out.shape == lead + (N,)
+    hlo = _compile(c2r, spec, spec)
+    assert not _reverse_fused_with_arithmetic(hlo)
+    assert ("tpu_custom_call" in hlo) == (tier == "pallas")
 
 
-def test_r2c_combine_compiles_for_v5e(one_chip):
-    """The first superstep of the sharded 512^3 rfft on one device: the
-    real (256, 256, 512) -> the half spectrum (256, 256, 257). Its
-    Hermitian combine reads the mirrored bins by a gather: the program
-    holds no reverse at all."""
+@pytest.mark.parametrize("batch", sorted(REAL_BATCHES))
+@pytest.mark.parametrize("tier", sorted(REAL_TIERS))
+def test_r2c_combine_compiles_for_v5e(one_chip, monkeypatch, tier, batch):
+    """The first superstep of the 512^3 rfft on one device: the real
+    (..., 512) -> the half spectrum (..., 257). Its Hermitian combine
+    reads the mirrored bins by a gather: the program holds no reverse
+    at all, whichever tier runs the half-length FFT."""
     from repro.fft import methods
+    monkeypatch.setattr(methods, "backend", lambda: "tpu")
+    lead = REAL_BATCHES[batch]
 
     def r2c(x):
-        return methods.apply_real(x, axis=-1, method="four_step")
+        return methods.apply_real(x, axis=-1, method="four_step",
+                                  kernel=tier)
 
-    x = jax.ShapeDtypeStruct(LOCAL + (N,), jnp.float32, sharding=one_chip)
-    assert jax.eval_shape(r2c, x)[0].shape == LOCAL + (N // 2 + 1,)
-    assert "reverse(" not in _compile(r2c, x)
+    x = jax.ShapeDtypeStruct(lead + (N,), jnp.float32, sharding=one_chip)
+    assert jax.eval_shape(r2c, x)[0].shape == lead + (N // 2 + 1,)
+    hlo = _compile(r2c, x)
+    assert "reverse(" not in hlo
+    assert ("tpu_custom_call" in hlo) == (tier == "pallas")
 
 
 @pytest.mark.parametrize("real", [False, True])

@@ -385,6 +385,27 @@ def test_resolved_kernel_covers_every_axis(monkeypatch):
         assert fft.plan(shape, mesh).resolved_kernel == 'reference', shape
 
 
+def test_resolved_kernel_real_axis_at_half_length(monkeypatch):
+    """A real plan's real axis runs its complex FFT at half length, and
+    resolves its tier there, as ``methods.apply_real`` does: where only
+    the half length fits ``PALLAS_MAX_N`` the real plan is on the Pallas
+    tier and the complex plan of the same shape is not."""
+    import repro.fft as fft
+    from repro.fft import methods
+    monkeypatch.setattr(methods, 'backend', lambda: 'tpu')
+    mesh = _abstract_mesh(4, 4)
+    n = 2 * methods.PALLAS_MAX_N
+    assert fft.rplan((64, 64, n), mesh).resolved_kernel == 'pallas'
+    assert fft.plan((64, 64, n), mesh).resolved_kernel == 'reference'
+    assert fft.rplan((64, 64, 2 * n), mesh).resolved_kernel == 'reference'
+    assert fft.rplan((64, n, 64), mesh).resolved_kernel == 'reference'
+    # rank 1: the real four-step's column factor n1 is the real axis
+    n1d = n * methods.PALLAS_MAX_N
+    assert fft.rplan((n1d,), mesh)._factors == (n, methods.PALLAS_MAX_N)
+    assert fft.rplan((n1d,), mesh).resolved_kernel == 'pallas'
+    assert fft.plan((n1d,), mesh).resolved_kernel == 'reference'
+
+
 def test_plan_rejects_unknown_kernel_tier():
     import repro.fft as fft
     mesh = _abstract_mesh(4, 4)
